@@ -52,6 +52,9 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--out", default="experiments/ablation_incoherence.json")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     results = run(args)
     print(json.dumps(results, indent=1))
     if args.out:

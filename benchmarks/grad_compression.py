@@ -25,13 +25,14 @@ import json
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.optim.compression import ef_int8_compress, init_ef_state
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.hlo_analysis import analyze_hlo
 
 
 def main(argv=None):
+    use_compile_cache()
     n_dev = len(jax.devices())
     mesh = Mesh(jax.devices(), ("data",))
     nelem = 1 << 20  # 1M-element gradient leaf (4 MB fp32)
@@ -44,7 +45,7 @@ def main(argv=None):
         def f(g):
             return jax.lax.psum(g, "data") / n_dev
 
-        return shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())(g)
+        return jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())(g)
 
     @jax.jit
     def sync_int8(g, e):
@@ -69,9 +70,9 @@ def main(argv=None):
             mean = (qg.astype(jnp.float32) * s2g[:, None]).reshape(-1)
             return mean, err["g"][None]
 
-        return shard_map(
+        return jax.shard_map(
             f, mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=(P(), P("data")), check_rep=False,
+            out_specs=(P(), P("data")), check_vma=False,
         )(g, e)
 
     results = {}

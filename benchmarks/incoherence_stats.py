@@ -109,6 +109,9 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--out", default="experiments/incoherence_stats.json")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     results = run(args)
     if args.out:
         import pathlib

@@ -57,6 +57,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="experiments/proxy_loss.json")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     results = run(args)
     print(json.dumps(results, indent=1))
     if args.out:

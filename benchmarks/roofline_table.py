@@ -31,6 +31,9 @@ def main(argv=None):
     ap.add_argument("--pod", default="pod1")
     ap.add_argument("--tag", default=None)
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     rows = load_rows(args.dir, args.pod, args.tag)
     print("| arch | shape | compute | memory | collective | dominant |"
           " useful | MFU-bound |")

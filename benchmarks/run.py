@@ -20,6 +20,9 @@ def main(argv=None):
     ap.add_argument("--only", default=None,
                     help="comma-separated module subset")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from benchmarks import (
         ablation_incoherence,

@@ -192,7 +192,13 @@ def run_fleet(args, cfg, prompts, lengths, arrivals):
     if args.quantize:
         replica_argv += ["--bits", str(args.bits)]
 
-    factory = ProcessReplicaFactory(replica_argv)
+    from repro.serve.fleet.supervisor import local_tpu_chips
+
+    chips = local_tpu_chips()
+    if chips and args.fleet > chips:
+        raise SystemExit(f"--fleet {args.fleet}: each replica needs a TPU "
+                         f"chip of its own and this host has {chips}")
+    factory = ProcessReplicaFactory(replica_argv, chips=chips)
     sup = Supervisor(factory, args.fleet, probe_interval_s=0.25,
                      start_timeout_s=600.0,
                      replica_drain_timeout_s=args.drain_timeout_s + 30.0)
@@ -524,6 +530,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_serving.json")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.speculative and not args.paged:
         ap.error("--speculative verifies drafts over the paged pool; "
                  "add --paged")
@@ -549,10 +558,10 @@ def main(argv=None):
     elif args.kill_mid_run:
         ap.error("--kill-mid-run kills a fleet replica; add --fleet N")
 
-    cfg = get_smoke_config(args.arch)
     if not args.smoke:
-        print("[serving_load] full-scale arch on CPU is impractical; "
-              "using the smoke config (pass --smoke to silence this)")
+        ap.error("this benchmark builds a float model with Model.init, "
+                 "which only fits at the smoke widths; pass --smoke")
+    cfg = get_smoke_config(args.arch)
     if args.fleet is not None:
         # fleet parent never builds a model — each replica subprocess
         # builds its own (same seed, identical weights); this process

@@ -99,6 +99,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_tp.json")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     if not args.smoke:

@@ -55,6 +55,9 @@ def main(argv=None):
     ap.add_argument("--train-steps", type=int, default=150)
     ap.add_argument("--out", default="experiments/trd_trh.json")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     results = run(args)
     print(json.dumps({k: v for k, v in results.items() if "per_layer" not in k},
                      indent=1))

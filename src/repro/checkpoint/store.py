@@ -30,6 +30,7 @@ import warnings
 from typing import Any, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
@@ -66,9 +67,7 @@ def _sha256(path: pathlib.Path) -> str:
 
 
 def _flatten_with_paths(tree):
-    # jax.tree.flatten_with_path only exists from jax 0.4.38; go through
-    # tree_util for compatibility with the pinned 0.4.x toolchain
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     items = []
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
@@ -128,6 +127,12 @@ def save_checkpoint(
         "shard_digests": [
             _sha256(tmp / f"shard_{i:05d}.npz") for i in range(len(shards))
         ],
+        # npz stores dtypes numpy lacks (bfloat16) as raw void bytes; the
+        # loader views them back through these names
+        "leaf_dtypes": {
+            k: str(v.dtype) for shard in shards for k, v in shard.items()
+            if v.dtype.kind == "V"
+        },
         "time": time.time(),
         "meta": extra_meta or {},
     }
@@ -193,6 +198,7 @@ def load_arrays(
         warnings.warn(
             f"{path} manifest predates shard checksums; loading unverified",
             stacklevel=2)
+    void_dtypes = manifest.get("leaf_dtypes", {})
     arrays: dict[str, Any] = {}
     for i in range(manifest["n_shards"]):
         spath = path / f"shard_{i:05d}.npz"
@@ -205,7 +211,10 @@ def load_arrays(
         with np.load(spath) as z:
             for k in z.files:
                 key = k.replace("::", "/")
-                arrays[key] = z[k] if placer is None else placer(key, z[k])
+                arr = z[k]
+                if key in void_dtypes:
+                    arr = arr.view(jnp.dtype(void_dtypes[key]))
+                arrays[key] = arr if placer is None else placer(key, arr)
     return arrays, step, manifest.get("meta", {})
 
 

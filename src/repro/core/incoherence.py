@@ -66,6 +66,7 @@ def _pow2_split(n: int) -> tuple[int, int]:
     return n, 1 << k
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class OrthogonalTransform:
     """A seeded structured orthogonal operator on R^n.
@@ -79,11 +80,14 @@ class OrthogonalTransform:
     Only factors/signs/permutation are stored — O(p² + q² + n), regenerable
     from ``seed`` alone, which is what makes shipping quantized checkpoints
     nearly free (Sec. 4.1).
+
+    A pytree: the factor arrays are leaves and ``kind``/``n``/``seed`` are
+    static, so a jitted program takes transforms as arguments.
     """
 
-    kind: TransformKind
-    n: int
-    seed: int
+    kind: TransformKind = dataclasses.field(metadata=dict(static=True))
+    n: int = dataclasses.field(metadata=dict(static=True))
+    seed: int = dataclasses.field(metadata=dict(static=True))
     A: Optional[jax.Array]  # (p, p) or None
     B: Optional[jax.Array]  # (q, q) or None
     signs: Optional[jax.Array]  # (q,) ±1 for hadamard
@@ -123,11 +127,7 @@ def make_transform(
         if pow2 == 1:
             raise ValueError(f"hadamard transform needs an even dim, got {n}")
         A = random_orthogonal(k_a, odd, dtype) if odd > 1 else None
-        signs = (
-            jax.random.rademacher(k_s, (pow2,), dtype=dtype)
-            if hasattr(jax.random, "rademacher")
-            else jnp.sign(jax.random.normal(k_s, (pow2,), dtype=dtype))
-        )
+        signs = jax.random.rademacher(k_s, (pow2,), dtype=dtype)
         return OrthogonalTransform(kind, n, seed, A, None, signs, perm, inv_perm)
     raise ValueError(f"unknown transform kind: {kind}")
 
@@ -230,6 +230,7 @@ def from_grid(Wq: jax.Array, s: jax.Array, maxq: int) -> jax.Array:
     return s * (Wq * (2.0 / maxq) - 1.0)
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class PreprocessState:
     """Everything needed to revert Algorithm 1 (and to run inference)."""
@@ -238,7 +239,7 @@ class PreprocessState:
     V: OrthogonalTransform  # n side
     D: Optional[jax.Array]  # (n,) diagonal rescale, or None
     s: jax.Array  # scalar quantization range
-    maxq: int
+    maxq: int = dataclasses.field(metadata=dict(static=True))
 
 
 def incoherence_preprocess(

@@ -56,20 +56,26 @@ class QuipConfig:
         return f"{self.method}{'+incp' if self.incoherence else ''}@{self.bits}b"
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class QuantizedLinear:
     """Inference-ready quantized linear layer: y = x @ W_eff^T.
 
     ``packed``: (packed_rows(n), m) int32 along the reduction dim.
     ``state``:  transforms + scales needed to apply/revert Alg. 2.
+
+    A pytree whose arrays are leaves (``bits``/``m``/``n``/``use_kernel``
+    static), so serving programs take layers as jit arguments instead of
+    compiling their weights in as constants.
     """
 
     packed: jax.Array
-    bits: int
-    m: int
-    n: int
+    bits: int = dataclasses.field(metadata=dict(static=True))
+    m: int = dataclasses.field(metadata=dict(static=True))
+    n: int = dataclasses.field(metadata=dict(static=True))
     state: inc.PreprocessState
-    use_kernel: bool = True
+    use_kernel: bool = dataclasses.field(default=True,
+                                         metadata=dict(static=True))
 
     def dequantize(self) -> jax.Array:
         """Materialize W_eff (m, n) — tests/export only."""
@@ -77,19 +83,23 @@ class QuantizedLinear:
         return inc.incoherence_postprocess(Wq, self.state)
 
     def __call__(
-        self, x: jax.Array, *, use_kernel: Optional[bool] = None
+        self, x: jax.Array, *, use_kernel: Optional[bool] = None,
+        matmul=None,
     ) -> jax.Array:
         """y = x @ W_eff^T with x (..., n) — structured inference path.
 
         ``use_kernel`` overrides the layer default for this call: the
         serving engine's paged decode passes ``True`` so every projection
         dispatches through the Pallas ``quant_matmul`` path (jnp oracle
-        off-TPU) regardless of how the layer was built.
+        off-TPU) regardless of how the layer was built.  ``matmul``, when
+        given, replaces the packed matmul ``h -> h @ deq(Wq)^T`` (the
+        tensor-parallel adapter runs it per shard).
         """
         st = self.state
         h = x if st.D is None else x / st.D
         h = inc.apply_transform(st.V, h)
-        z = self._matmul(h, use_kernel=use_kernel)
+        z = self._matmul(h, use_kernel=use_kernel) if matmul is None \
+            else matmul(h)
         return inc.apply_transform(st.U, z, inverse=True)
 
     def _matmul(

@@ -16,7 +16,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterator, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -94,7 +93,7 @@ def token_batches(
 class CalibrationSet:
     """Paper Sec. 6: 128 random segments of 2048 tokens (scaled-down knobs)."""
 
-    tokens: jax.Array  # (n_seg, seg_len)
+    tokens: np.ndarray  # (n_seg, seg_len) int32, host memory
 
     @property
     def n_segments(self) -> int:
@@ -119,4 +118,6 @@ def make_calibration(
     src = SyntheticLM(vocab, source_seed)
     rng = np.random.default_rng(seed)
     toks = src.sample(rng, n_segments, seg_len)
-    return CalibrationSet(tokens=jnp.asarray(toks))
+    # numpy, not a device array: a process that only builds prompts (a
+    # fleet parent) must not start a backend and take the chip
+    return CalibrationSet(tokens=np.asarray(toks, np.int32))

@@ -22,8 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def sylvester(n: int) -> np.ndarray:
     """Unnormalized H_n (n a power of two) via Sylvester's construction."""
@@ -84,7 +82,7 @@ def hadamard_kernel(
         ],
         out_specs=pl.BlockSpec((bB, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, n), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kron_kernel(x_ref, a_ref, b_ref, o_ref, *, p: int, q: int):
     bB = x_ref.shape[0]
@@ -77,7 +75,7 @@ def kron_mul_kernel(
         ],
         out_specs=pl.BlockSpec((bB, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, n), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

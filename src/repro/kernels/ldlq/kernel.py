@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _ldlq_kernel(w_ref, b_ref, u_ref, q_ref, e_ref, *, nb: int, maxq: int):
     W = w_ref[...].astype(jnp.float32)  # (bM, nb) raw block weights
@@ -89,7 +87,7 @@ def ldlq_block_kernel(
             jax.ShapeDtypeStruct((M, nb), jnp.float32),
             jax.ShapeDtypeStruct((M, nb), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

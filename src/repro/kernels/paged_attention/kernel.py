@@ -15,6 +15,11 @@ O(allocated-pages) HBM copy per emitted token that un-does the bandwidth win
 2-bit weights buy (DESIGN.md §7).  This kernel reads the **physical page
 pool in place**:
 
+* the pool is head-major, ``(L, P, KV, ps, hd)``, so one grid step's
+  ``(ps, hd)`` page slice of one kv head is a whole trailing tile: the
+  TPU lowering requires the last two block dims to be multiples of
+  (8, 128) or the full array dims, which a ``(ps, 1, hd)`` block of a
+  token-major ``(ps, KV, hd)`` page breaks for any ``KV > 1``;
 * the grid is ``(lane, kv_head, page)`` with the page dimension innermost
   ("arbitrary"), so the fp32 output tile is revisited as an online-softmax
   accumulator (running max ``m``, normalizer ``l``, unnormalized ``o``);
@@ -34,9 +39,11 @@ pool in place**:
   must hold a real page id (the engine zero-fills block tables, and
   physical page 0 is the reserved scratch page);
 * the layer index is baked into the index map, so the kernel addresses the
-  full ``(L, P, ps, KV, hd)`` pool tensor without an XLA slice copy;
-* int8 pages carry per-(token, head) fp32 scales (``(L, P, ps, KV)``),
-  dequantized on the VPU right after the DMA — KV reads stay 1 byte/elem.
+  full ``(L, P, KV, ps, hd)`` pool tensor without an XLA slice copy;
+* int8 pages carry per-(head, token) fp32 scales (``(L, P, KV, ps)``); a
+  step reads the page's whole ``(KV, ps)`` scale tile, picks its head's
+  row, and applies it to the score and probability rows (a row scale on
+  ``q·kᵀ`` columns is a scale on k's rows) — KV reads stay 1 byte/elem.
 
 The new token's own K/V never touches the pool here: the wrapper (ops.py)
 folds the self-attention term into the accumulator analytically and
@@ -52,15 +59,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 _NEG = float(jnp.finfo(jnp.float32).min)
 
 
-def _online_update(s, valid, v, o_ref, m_ref, l_ref):
+def _online_update(s, valid, v, o_ref, m_ref, l_ref, v_row=None):
     """One online-softmax accumulation step over a key block.
 
-    s (R, S) scores already NEG-filled outside ``valid``; v (S, hd).
+    s (R, S) scores already NEG-filled outside ``valid``; v (S, hd);
+    ``v_row`` optional (1, S) per-key value scale (int8 pages).
     ``pmat`` is gated explicitly so a fully-masked block contributes
     exactly zero (``exp(NEG - NEG) == 1`` would poison the accumulator).
     """
@@ -69,27 +75,50 @@ def _online_update(s, valid, v, o_ref, m_ref, l_ref):
     alpha = jnp.exp(m_prev - m_new)
     pmat = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # (R, S)
     l_ref[0, 0] = alpha * l_ref[0, 0] + jnp.sum(pmat, -1, keepdims=True)
+    pv = pmat if v_row is None else pmat * v_row
     o_ref[0, 0] = o_ref[0, 0] * alpha + jax.lax.dot_general(
-        pmat, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     m_ref[0, 0] = m_new
+
+
+def _head_row(sc_ref, h):
+    """Row ``h`` of a page's (KV, ps) scale tile as a (1, ps) vector — a
+    masked sublane reduction, which lowers for any KV."""
+    sc = sc_ref[0, 0]  # (KV, ps)
+    rows = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+    return jnp.sum(jnp.where(rows == h, sc, 0.0), axis=0, keepdims=True)
+
+
+def _page_scores(q, k_ref, v_ref, ks_ref, vs_ref, h, int8_pages):
+    """Scores (R, ps) of queries q (R, hd) against one page of head h,
+    plus that page's values (ps, hd) and optional (1, ps) value scale."""
+    k = k_ref[0, 0, 0].astype(jnp.float32)  # (ps, hd)
+    v = v_ref[0, 0, 0].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * (q.shape[-1] ** -0.5)
+    if not int8_pages:
+        return s, v, None
+    return s * _head_row(ks_ref, h), v, _head_row(vs_ref, h)
 
 
 def _pa_kernel(
     bt_ref,  # (B, Pa) int32 scalar-prefetch block table
     cl_ref,  # (B,)    int32 scalar-prefetch context lengths
     q_ref,  # (1, 1, G, hd)
-    k_ref,  # (1, 1, ps, 1, hd)
-    v_ref,  # (1, 1, ps, 1, hd)
-    *refs,  # [ks_ref (1,1,ps,1), vs_ref (1,1,ps,1)], o_ref, m_ref, l_ref
+    k_ref,  # (1, 1, 1, ps, hd)
+    v_ref,  # (1, 1, 1, ps, hd)
+    *refs,  # [ks_ref (1,1,KV,ps), vs_ref (1,1,KV,ps)], o_ref, m_ref, l_ref
     page_size: int,
     int8_pages: bool,
 ):
     if int8_pages:
         ks_ref, vs_ref, o_ref, m_ref, l_ref = refs
     else:
+        ks_ref = vs_ref = None
         o_ref, m_ref, l_ref = refs
-    b, p = pl.program_id(0), pl.program_id(2)
+    b, h, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(p == 0)
     def _init():
@@ -98,15 +127,7 @@ def _pa_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)  # (G, hd)
-    k = k_ref[0, 0, :, 0].astype(jnp.float32)  # (ps, hd)
-    v = v_ref[0, 0, :, 0].astype(jnp.float32)
-    if int8_pages:
-        k = k * ks_ref[0, 0, :, 0][:, None]
-        v = v * vs_ref[0, 0, :, 0][:, None]
-    hd = q.shape[-1]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * (hd**-0.5)  # (G, ps)
+    s, v, v_row = _page_scores(q, k_ref, v_ref, ks_ref, vs_ref, h, int8_pages)
 
     # positions covered by this physical page; everything at or past the
     # lane's ctx_len (incl. whole pages resolved to the scratch page) is
@@ -116,7 +137,7 @@ def _pa_kernel(
     )
     valid = pos < cl_ref[b]  # (1, ps), broadcasts over G
     s = jnp.where(valid, s, _NEG)
-    _online_update(s, valid, v, o_ref, m_ref, l_ref)
+    _online_update(s, valid, v, o_ref, m_ref, l_ref, v_row)
 
 
 def _check_operands(q, k_pages, v_pages, block_tables, ctx_len, layer,
@@ -128,10 +149,10 @@ def _check_operands(q, k_pages, v_pages, block_tables, ctx_len, layer,
     B, KV, G, hd = q.shape
     if k_pages.ndim != 5 or v_pages.shape != k_pages.shape:
         raise ValueError(
-            "k_pages/v_pages must both be (L, n_pages, page_size, KV, hd); "
+            "k_pages/v_pages must both be (L, n_pages, KV, page_size, hd); "
             f"got k_pages {k_pages.shape}, v_pages {v_pages.shape}"
         )
-    L, P, ps, KVp, hdp = k_pages.shape
+    L, P, KVp, ps, hdp = k_pages.shape
     if (KVp, hdp) != (KV, hd):
         raise ValueError(
             f"page pool carries (KV={KVp}, hd={hdp}) but queries expect "
@@ -150,9 +171,9 @@ def _check_operands(q, k_pages, v_pages, block_tables, ctx_len, layer,
     if int8_pages:
         if k_scale is None or v_scale is None:
             raise ValueError("int8 pages require k_scale and v_scale")
-        if k_scale.shape != (L, P, ps, KV) or v_scale.shape != (L, P, ps, KV):
+        if k_scale.shape != (L, P, KV, ps) or v_scale.shape != (L, P, KV, ps):
             raise ValueError(
-                f"page scales must be (L, P, ps, KV)={(L, P, ps, KV)}, got "
+                f"page scales must be (L, P, KV, ps)={(L, P, KV, ps)}, got "
                 f"k_scale {k_scale.shape}, v_scale {v_scale.shape}"
             )
     elif k_scale is not None or v_scale is not None:
@@ -176,7 +197,7 @@ def paged_attention_kernel(
     """Online-softmax decode attention of layer ``layer`` against the pool.
 
     q            (B, KV, G, hd) — grouped post-RoPE queries, one token/lane;
-    k/v_pages    (L, P, ps, KV, hd) physical pool (fp, or int8 + scales);
+    k/v_pages    (L, P, KV, ps, hd) physical pool (fp, or int8 + scales);
     block_tables (B, Pa) int32 physical page per logical page (Pa is the
                  *attended* prefix of the lane's allocation, bucketed by the
                  caller — step cost scales with context, not allocation);
@@ -190,26 +211,8 @@ def paged_attention_kernel(
         q, k_pages, v_pages, block_tables, ctx_len, layer, k_scale, v_scale
     )
     B, KV, G, hd = q.shape
-    ps = k_pages.shape[2]
     Pa = block_tables.shape[1]
-
-    def _page(bt, cl, b, p):
-        # clamp to the lane's last valid page: grid steps past a short
-        # lane's context re-DMA the block already in VMEM (Mosaic elides
-        # the copy), instead of streaming dead/scratch pages to mask them.
-        # An EMPTY lane (cl == 0) clamps to entry 0, which must be a valid
-        # page id (engine convention: zero-fill -> scratch page 0)
-        last = jnp.maximum(pl.cdiv(cl[b], ps) - 1, 0)
-        return bt[b, jnp.minimum(p, last)]
-
-    kv_spec = pl.BlockSpec(
-        (1, 1, ps, 1, hd),
-        lambda b, h, p, bt, cl: (layer, _page(bt, cl, b, p), 0, h, 0),
-    )
-    sc_spec = pl.BlockSpec(
-        (1, 1, ps, 1),
-        lambda b, h, p, bt, cl: (layer, _page(bt, cl, b, p), 0, h),
-    )
+    kv_spec, sc_spec = _pool_specs(k_pages.shape, layer)
     in_specs = [
         pl.BlockSpec((1, 1, G, hd), lambda b, h, p, bt, cl: (b, h, 0, 0)),
         kv_spec,
@@ -232,7 +235,7 @@ def paged_attention_kernel(
     )
     return pl.pallas_call(
         functools.partial(
-            _pa_kernel, page_size=ps, int8_pages=int8_pages
+            _pa_kernel, page_size=k_pages.shape[3], int8_pages=int8_pages
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -240,23 +243,49 @@ def paged_attention_kernel(
             jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables, ctx_len, *operands)
+
+
+def _pool_specs(pool_shape, layer):
+    """BlockSpecs of one (head, page) step over the head-major pool and of
+    the page's whole (KV, ps) scale tile.  The page index is clamped to
+    the lane's last valid page: grid steps past a short lane's context
+    re-DMA the block already in VMEM (Mosaic elides the copy), instead of
+    streaming dead/scratch pages to mask them.  An EMPTY lane (cl == 0)
+    clamps to entry 0, which must be a valid page id (engine convention:
+    zero-fill -> scratch page 0)."""
+    _, _, KV, ps, hd = pool_shape
+
+    def page(bt, cl, b, p):
+        last = jnp.maximum(pl.cdiv(cl[b], ps) - 1, 0)
+        return bt[b, jnp.minimum(p, last)]
+
+    kv_spec = pl.BlockSpec(
+        (1, 1, 1, ps, hd),
+        lambda b, h, p, bt, cl: (layer, page(bt, cl, b, p), h, 0, 0),
+    )
+    sc_spec = pl.BlockSpec(
+        (1, 1, KV, ps),
+        lambda b, h, p, bt, cl: (layer, page(bt, cl, b, p), 0, 0),
+    )
+    return kv_spec, sc_spec
 
 
 def _prefill_kernel(
     bt_ref,  # (B, Pa) int32 scalar-prefetch block table
     cl_ref,  # (B,)    int32 scalar-prefetch PRIOR-context lengths
     q_ref,  # (1, 1, G*C, hd) chunk queries, rows G-major / chunk-pos-minor
-    k_ref,  # (1, 1, ps, 1, hd) one physical context page
-    v_ref,  # (1, 1, ps, 1, hd)
-    kc_ref,  # (1, C, 1, hd) the chunk's own K (not yet in the pool)
-    vc_ref,  # (1, C, 1, hd)
-    *refs,  # [kself_ref, vself_ref (1,C,1,hd)], [ks_ref, vs_ref
-    #         (1,1,ps,1)], o_ref, m_ref, l_ref
+    k_ref,  # (1, 1, 1, ps, hd) one physical context page of one head
+    v_ref,  # (1, 1, 1, ps, hd)
+    kc_ref,  # (1, 1, C, hd) the chunk's own K (not yet in the pool)
+    vc_ref,  # (1, 1, C, hd)
+    *refs,  # [kself_ref, vself_ref (1,1,C,hd)], [ks_ref, vs_ref
+    #         (1,1,KV,ps)], o_ref, m_ref, l_ref
     page_size: int,
     chunk: int,
     int8_pages: bool,
@@ -267,8 +296,9 @@ def _prefill_kernel(
     if int8_pages:
         ks_ref, vs_ref, o_ref, m_ref, l_ref = refs
     else:
+        ks_ref = vs_ref = None
         o_ref, m_ref, l_ref = refs
-    b, p = pl.program_id(0), pl.program_id(2)
+    b, h, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_ctx = pl.num_programs(2) - 1  # trailing step is the chunk block
 
     @pl.when(p == 0)
@@ -285,21 +315,15 @@ def _prefill_kernel(
         # identical to the decode page step: every chunk token attends all
         # prior-context positions < cl, so the whole (G*C)-row tile shares
         # one page mask.
-        k = k_ref[0, 0, :, 0].astype(jnp.float32)  # (ps, hd)
-        v = v_ref[0, 0, :, 0].astype(jnp.float32)
-        if int8_pages:
-            k = k * ks_ref[0, 0, :, 0][:, None]
-            v = v * vs_ref[0, 0, :, 0][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (G*C, ps)
+        s, v, v_row = _page_scores(
+            q, k_ref, v_ref, ks_ref, vs_ref, h, int8_pages
+        )  # (G*C, ps)
         pos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1
         )
         valid = pos < cl_ref[b]
         s = jnp.where(valid, s, _NEG)
-        _online_update(s, valid, v, o_ref, m_ref, l_ref)
+        _online_update(s, valid, v, o_ref, m_ref, l_ref, v_row)
 
     @pl.when(p == n_ctx)
     def _chunk_block():
@@ -307,8 +331,8 @@ def _prefill_kernel(
         # r % chunk (G-major row layout), key column t valid iff t <= pos.
         # Every row keeps its self column, so the merged softmax is finite
         # even for ctx_len == 0 lanes and padded tail tokens.
-        kc = kc_ref[0, :, 0].astype(jnp.float32)  # (C, hd)
-        vc = vc_ref[0, :, 0].astype(jnp.float32)
+        kc = kc_ref[0, 0].astype(jnp.float32)  # (C, hd)
+        vc = vc_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kc, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -321,7 +345,7 @@ def _prefill_kernel(
         if has_self:
             # diagonal override (speculative verify): each token's score
             # to ITSELF comes from the fp self K, not the chunk array
-            kself = kself_ref[0, :, 0].astype(jnp.float32)  # (C, hd)
+            kself = kself_ref[0, 0].astype(jnp.float32)  # (C, hd)
             s_self = jax.lax.dot_general(
                 q, kself, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -344,7 +368,7 @@ def _prefill_kernel(
                 pmat, vc, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            vd = vself_ref[0, :, 0].astype(jnp.float32) - vc
+            vd = vself_ref[0, 0].astype(jnp.float32) - vc
             acc = acc + jax.lax.dot_general(
                 jnp.where(diag, pmat, 0.0), vd, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -408,7 +432,7 @@ def paged_prefill_kernel(
                  chunk token t sits at absolute position ``ctx_len[b] + t``;
     k/v_chunk    (B, C, KV, hd) the chunk's own post-RoPE K/V (NOT yet
                  scattered into the pool);
-    k/v_pages    (L, P, ps, KV, hd) physical pool (fp, or int8 + scales);
+    k/v_pages    (L, P, KV, ps, hd) physical pool (fp, or int8 + scales);
     block_tables (B, Pa) int32, bucketed to the longest PRIOR context;
     ctx_len      (B,) int32 valid prior-context tokens per lane (the chunk's
                  start position) — ragged, 0 for fresh admissions;
@@ -428,27 +452,15 @@ def paged_prefill_kernel(
     )
     has_self = k_self is not None
     B, KV, G, C, hd = q.shape
-    ps = k_pages.shape[2]
     Pa = block_tables.shape[1]
     qf = q.reshape(B, KV, G * C, hd)
-
-    def _page(bt, cl, b, p):
-        # same clamp as decode: steps at/past a lane's last valid page
-        # (including the whole trailing chunk step) re-ask for the page
-        # already resident, and Mosaic elides the DMA.
-        last = jnp.maximum(pl.cdiv(cl[b], ps) - 1, 0)
-        return bt[b, jnp.minimum(p, last)]
-
-    kv_spec = pl.BlockSpec(
-        (1, 1, ps, 1, hd),
-        lambda b, h, p, bt, cl: (layer, _page(bt, cl, b, p), 0, h, 0),
-    )
-    sc_spec = pl.BlockSpec(
-        (1, 1, ps, 1),
-        lambda b, h, p, bt, cl: (layer, _page(bt, cl, b, p), 0, h),
-    )
+    # the same clamp as decode: steps at/past a lane's last valid page
+    # (including the whole trailing chunk step) re-ask for the resident page
+    kv_spec, sc_spec = _pool_specs(k_pages.shape, layer)
+    # chunk K/V go head-major too, so each step reads one (C, hd) tile
+    heads_major = lambda x: x.transpose(0, 2, 1, 3)  # (B, KV, C, hd)
     chunk_spec = pl.BlockSpec(
-        (1, C, 1, hd), lambda b, h, p, bt, cl: (b, 0, h, 0)
+        (1, 1, C, hd), lambda b, h, p, bt, cl: (b, h, 0, 0)
     )
     in_specs = [
         pl.BlockSpec(
@@ -459,10 +471,11 @@ def paged_prefill_kernel(
         chunk_spec,
         chunk_spec,
     ]
-    operands = [qf, k_pages, v_pages, k_chunk, v_chunk]
+    operands = [qf, k_pages, v_pages, heads_major(k_chunk),
+                heads_major(v_chunk)]
     if has_self:
         in_specs += [chunk_spec, chunk_spec]
-        operands += [k_self, v_self]
+        operands += [heads_major(k_self), heads_major(v_self)]
     if int8_pages:
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale, v_scale]
@@ -485,7 +498,7 @@ def paged_prefill_kernel(
     )
     o, _, _ = pl.pallas_call(
         functools.partial(
-            _prefill_kernel, page_size=ps, chunk=C, int8_pages=int8_pages,
+            _prefill_kernel, page_size=k_pages.shape[3], chunk=C, int8_pages=int8_pages,
             has_self=has_self,
         ),
         grid_spec=grid_spec,
@@ -494,9 +507,10 @@ def paged_prefill_kernel(
             jax.ShapeDtypeStruct((B, KV, G * C, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, KV, G * C, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(block_tables, ctx_len, *operands)
     return o.reshape(B, KV, G, C, hd)

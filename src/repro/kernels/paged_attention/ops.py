@@ -56,7 +56,7 @@ def paged_gqa_decode(
     """One-token GQA decode attention against the physical page pool.
 
     q (B, H, hd) post-RoPE queries; k_new/v_new (B, KV, hd) the token's own
-    post-RoPE K/V (not yet scattered); k/v_pages the full (L, P, ps, KV, hd)
+    post-RoPE K/V (not yet scattered); k/v_pages the full (L, P, KV, ps, hd)
     pool (+ per-(token, head) scales for int8 pages); block_tables (B, Pa)
     bucketed to the attended prefix; ctx_len (B,).  -> (B, H, hd) q.dtype.
     """
@@ -115,7 +115,7 @@ def paged_gqa_prefill(
     q (B, C, H, hd) post-RoPE chunk queries (lane b's token t at absolute
     position ``ctx_len[b] + t``); k_chunk/v_chunk (B, C, KV, hd) the
     chunk's own post-RoPE K/V (not yet scattered); k/v_pages the full
-    (L, P, ps, KV, hd) pool (+ per-(token, head) scales for int8 pages);
+    (L, P, KV, ps, hd) pool (+ per-(head, token) scales for int8 pages);
     block_tables (B, Pa) bucketed to the longest prior context; ctx_len
     (B,) ragged prior-context lengths; k/v_self optional (B, C, KV, hd)
     DIAGONAL override — each token's attention to itself uses these

@@ -14,13 +14,12 @@ import jax.numpy as jnp
 
 
 def _gather_layer(pages, scale, layer, block_tables):
-    """(L, P, ps, KV, hd)[layer, bt] -> (B, Pa*ps, KV, hd) fp32."""
-    g = pages[layer, block_tables]  # (B, Pa, ps, KV, hd)
-    g = g.astype(jnp.float32)
+    """(L, P, KV, ps, hd)[layer, bt] -> (B, Pa*ps, KV, hd) fp32."""
+    g = pages[layer, block_tables].astype(jnp.float32)  # (B, Pa, KV, ps, hd)
     if scale is not None:
         g = g * scale[layer, block_tables][..., None]
-    B = g.shape[0]
-    return g.reshape(B, -1, *pages.shape[-2:])
+    B, _, KV, _, hd = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, hd)
 
 
 def paged_gqa_decode_ref(
@@ -39,7 +38,7 @@ def paged_gqa_decode_ref(
     """One-token GQA attention vs paged context + the token itself.
 
     q (B, H, hd); k_new/v_new (B, KV, hd) — the token's own (post-RoPE) K/V,
-    NOT yet in the pool; k/v_pages (L, P, ps, KV, hd); block_tables (B, Pa);
+    NOT yet in the pool; k/v_pages (L, P, KV, ps, hd); block_tables (B, Pa);
     ctx_len (B,).  Returns (B, H, hd) in q.dtype.
     """
     B, H, hd = q.shape
@@ -85,7 +84,7 @@ def paged_gqa_prefill_ref(
 
     q (B, C, H, hd) post-RoPE chunk queries; k_chunk/v_chunk (B, C, KV, hd)
     the chunk's own (post-RoPE) K/V, NOT yet in the pool; k/v_pages
-    (L, P, ps, KV, hd); block_tables (B, Pa); ctx_len (B,) prior-context
+    (L, P, KV, ps, hd); block_tables (B, Pa); ctx_len (B,) prior-context
     tokens per lane.  Chunk token t of lane b attends context positions
     ``< ctx_len[b]`` plus chunk positions ``<= t``.  -> (B, C, H, hd).
 

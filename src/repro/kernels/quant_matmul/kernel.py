@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _qmm_kernel(x_ref, p_ref, o_ref, *, bits: int, n_k_tiles: int):
     k = pl.program_id(2)
@@ -42,11 +40,15 @@ def _qmm_kernel(x_ref, p_ref, o_ref, *, bits: int, n_k_tiles: int):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     vals = 32 // bits
-    mask = jnp.uint32(2**bits - 1)
-    words = p_ref[...].astype(jnp.uint32)  # (bKp, bM)
+    words = p_ref[...]  # (bKp, bM) int32
     bkp, bm = words.shape
-    shifts = (jnp.arange(vals, dtype=jnp.uint32) * bits)[None, :, None]
-    w = ((words[:, None, :] >> shifts) & mask).astype(jnp.float32)
+    # unpack in int32: the TPU compiler has no uint32 -> float32 cast, and a
+    # logical shift keeps the sign bit out of the top field
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (bkp, vals, bm), 1) * bits
+    fields = jax.lax.shift_right_logical(
+        jnp.broadcast_to(words[:, None, :], (bkp, vals, bm)), shifts
+    )
+    w = (fields & (2**bits - 1)).astype(jnp.float32)
     w = w.reshape(bkp * vals, bm)  # (bK, bM) grid values, K-major
     x = x_ref[...].astype(jnp.float32)
     o_ref[...] += jax.lax.dot_general(
@@ -100,8 +102,9 @@ def quant_matmul_kernel(
         ],
         out_specs=pl.BlockSpec((bB, bM), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="quant_matmul",
     )(x, packed)

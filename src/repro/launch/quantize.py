@@ -69,6 +69,17 @@ class QuantizedModel:
     def logits(self, tokens: jax.Array) -> jax.Array:
         return L.lm_logits(self.embed, self.forward_hidden(tokens))
 
+    def reference(self) -> "QuantizedModel":
+        """This model with every linear on the jnp dequantize-and-matmul
+        path: an oracle that never runs the Pallas kernel it checks."""
+        blocks = [
+            {name: dataclasses.replace(v, use_kernel=False)
+             if isinstance(v, QuantizedLinear) else v
+             for name, v in blk.items()}
+            for blk in self.blocks
+        ]
+        return dataclasses.replace(self, blocks=blocks)
+
     def cached_decoder(self):
         """KV-cached prefill/decode path (repro.serve) through the packed
         D^-1 -> V -> quant_matmul -> U^T pipeline — the serving-time
@@ -177,9 +188,14 @@ def block_hessians(
     chunk = B if chunk <= 0 else min(chunk, B)
     accs: dict[str, HessianAccumulator] = {}
     for i0 in range(0, B, chunk):
-        _, taps = _block_taps(lp, x[i0 : i0 + chunk], cfg, positions)
+        # the forward runs one segment at a time: batched matmuls are not
+        # batch-size-invariant on every backend, and H must not depend on
+        # how the batch was chunked
+        seg_taps = [_block_taps(lp, x[j : j + 1], cfg, positions)[1]
+                    for j in range(i0, min(i0 + chunk, B))]
         for name in _block_linears(cfg):
-            X = taps[name].astype(jnp.float32)
+            X = jnp.concatenate([t[name] for t in seg_taps]).astype(
+                jnp.float32)
             acc = accs.get(name) or HessianAccumulator.create(X.shape[-1])
             accs[name] = acc.update_segments(X)
     return {name: acc.finalize() for name, acc in accs.items()}
@@ -296,6 +312,9 @@ def main(argv=None):
                          "(packed ints + scales + transform seeds); serve "
                          "with launch/serve.py --load-quantized <dir>")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family not in ("dense",):

@@ -156,24 +156,51 @@ def greedy_generate(model, params, prompt, gen: int, kv_dtype=None):
 def quantized_generate(qm, prompt, gen: int):
     """Reference recompute path: full-prefix quantized forward per token
     (O(S^2) per token — kept as the equivalence oracle for the engine's
-    cached decode; see tests/test_serve.py)."""
+    cached decode; see tests/test_serve.py).  Every linear runs the jnp
+    dequantize-and-matmul path, never the Pallas kernel under test."""
+    ref = qm.reference()
     toks = prompt
     for _ in range(gen):
-        logits = qm.logits(toks)[:, -1]
+        logits = ref.logits(toks)[:, -1]
         toks = jnp.concatenate([toks, jnp.argmax(logits, -1)[:, None]], axis=1)
     return toks[:, prompt.shape[1]:]
+
+
+def _by_length(generate, prompts: list) -> list:
+    """Run a batched reference ``generate(batch)`` once per distinct
+    prompt length; returns one output row per prompt, in order."""
+    out = [None] * len(prompts)
+    for n in sorted({len(p) for p in prompts}):
+        idx = [i for i, p in enumerate(prompts) if len(p) == n]
+        rows = np.asarray(generate(np.stack([prompts[i] for i in idx])))
+        for i, row in zip(idx, rows):
+            out[i] = row
+    return out
+
+
+def _prompt_lengths(spec: str) -> tuple:
+    """``--prompt-len``: one length, or a comma list cycled over the
+    requests (ragged prompts)."""
+    try:
+        lens = tuple(int(x) for x in spec.split(","))
+    except ValueError:
+        raise argparse.ArgumentTypeError(f"expected N or N,N,..., got {spec!r}")
+    if min(lens) < 1:
+        raise argparse.ArgumentTypeError(f"prompt lengths must be >= 1: {spec!r}")
+    return lens
 
 
 def build_engine(adapter, *, max_seq_len, args, paged=None,
                  paged_prefill=None, prefix_cache=None,
                  speculative=None, faults=None, robust=True,
-                 tenants=None) -> "Engine":
+                 tenants=None, record_logits=False) -> "Engine":
     from repro.serve import Engine, EngineConfig
 
     paged = getattr(args, "paged", False) if paged is None else paged
     ecfg = EngineConfig(
         tenants=tenants if robust else None,
         max_seq_len=max_seq_len,
+        record_logits=record_logits,
         n_slots=args.slots,
         page_size=args.page_size,
         n_pages=args.pages,
@@ -227,13 +254,20 @@ def _serve_batch_fallback(model, params, prompts, args) -> int:
     return 0
 
 
-def main(argv=None):
+def main(argv=None, *, on_done=None):
+    """CLI entry.  ``on_done(engine, submitted)``, when given, is called
+    after the fixed (non-HTTP) workload ran, with the engine (built to
+    keep every emission's logits) and the accepted ``(prompt index,
+    request)`` pairs — for in-process callers that check numerics."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--requests", type=int, default=6,
                     help="number of concurrent requests to serve")
-    ap.add_argument("--prompt-len", type=int, default=32)
+    ap.add_argument("--prompt-len", type=_prompt_lengths, default=(32,),
+                    metavar="N[,N...]",
+                    help="prompt tokens per request; a comma list is "
+                         "cycled over the requests (ragged prompts)")
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--arrival-gap", type=float, default=0.02,
                     help="stagger between request arrivals (s)")
@@ -414,6 +448,9 @@ def main(argv=None):
                          "--quality-baseline regression instead of warning")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from repro.serve import CachedDecoder, DistributedCachedDecoder, \
         make_serving_mesh
@@ -519,6 +556,14 @@ def main(argv=None):
             ProcessReplicaFactory,
             Supervisor,
         )
+        from repro.serve.fleet.supervisor import local_tpu_chips
+
+        chips = local_tpu_chips()
+        if chips and args.fleet > chips:
+            raise SystemExit(
+                f"--fleet {args.fleet}: each replica needs a TPU chip of "
+                f"its own and this host has {chips}"
+            )
 
         first_spawn: dict[int, list] = {}
         for spec in args.replica_fault or ():
@@ -541,7 +586,7 @@ def main(argv=None):
             list(argv) if argv is not None else sys.argv[1:])
         factory = ProcessReplicaFactory(
             [sys.executable, "-m", "repro.launch.serve", *tail],
-            host=args.http_host, first_spawn_args=first_spawn,
+            host=args.http_host, first_spawn_args=first_spawn, chips=chips,
         )
         sup = Supervisor(
             factory, args.fleet, host=args.http_host,
@@ -646,9 +691,14 @@ def main(argv=None):
                     "that reference path (engine adapter is dense-only; "
                     "ROADMAP open item) — nothing to check"
                 )
+            if len(set(args.prompt_len)) > 1:
+                raise SystemExit(
+                    "the batch fallback serves one fixed-shape batch; "
+                    "give one --prompt-len"
+                )
             prompts = make_calibration(
-                cfg.vocab, n_segments=args.requests, seg_len=args.prompt_len,
-                seed=args.seed + 3,
+                cfg.vocab, n_segments=args.requests,
+                seg_len=args.prompt_len[0], seed=args.seed + 3,
             ).tokens
             return _serve_batch_fallback(model, params, prompts, args)
         if args.quantize:
@@ -671,14 +721,19 @@ def main(argv=None):
             )
             label = "fp"
 
-    prompts = make_calibration(
-        cfg.vocab, n_segments=args.requests, seg_len=args.prompt_len,
+    max_prompt = max(args.prompt_len)
+    calib = make_calibration(
+        cfg.vocab, n_segments=args.requests, seg_len=max_prompt,
         seed=args.seed + 3,
     ).tokens
+    prompts = [
+        calib[i, : args.prompt_len[i % len(args.prompt_len)]]
+        for i in range(args.requests)
+    ]
 
     engine = build_engine(
-        adapter, max_seq_len=args.prompt_len + args.gen, args=args,
-        faults=faults, tenants=tenants,
+        adapter, max_seq_len=max_prompt + args.gen, args=args,
+        faults=faults, tenants=tenants, record_logits=on_done is not None,
     )
     if args.canary_every is not None:
         # pinned OFF the traffic seed stream: the canary set must stay
@@ -844,6 +899,8 @@ def main(argv=None):
               f"(dropped={tracer.dropped}) coverage={pb['coverage']:.0%} "
               f"{phases}")
 
+    if on_done is not None:
+        on_done(engine, submitted)
     if args.check:
         if args.kv_int8 and not (args.paged or args.paged_prefill):
             raise SystemExit(
@@ -865,7 +922,7 @@ def main(argv=None):
                     else CachedDecoder.from_model(model, params)
                 )
             oracle = build_engine(
-                oracle_adapter, max_seq_len=args.prompt_len + args.gen,
+                oracle_adapter, max_seq_len=max_prompt + args.gen,
                 args=args, paged=False, paged_prefill=False,
                 prefix_cache=False, speculative=0, robust=False,
             )
@@ -880,10 +937,16 @@ def main(argv=None):
             ])
             ref_label = "gather-dense int8 engine"
         elif qm is not None:
-            ref = np.asarray(quantized_generate(qm, jnp.asarray(prompts), args.gen))
+            ref = _by_length(
+                lambda b: quantized_generate(qm, jnp.asarray(b), args.gen),
+                prompts,
+            )
             ref_label = "quantized recompute"
         else:
-            ref = np.asarray(greedy_generate(model, params, prompts, args.gen))
+            ref = _by_length(
+                lambda b: greedy_generate(model, params, b, args.gen),
+                prompts,
+            )
             ref_label = "fp prefill/decode"
         # FINISHED rows must match the oracle token-for-token at full
         # length; CANCELLED/FAILED rows must be an exact PREFIX of it —

@@ -59,7 +59,7 @@ so cached logits match it bit-for-bit up to matmul reassociation.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +75,7 @@ from repro.kernels.paged_attention.ops import (
 from repro.models import layers as L
 from repro.models.transformer import unstack_layers
 from repro.serve.faults import NO_FAULTS, FaultPlan
-from repro.serve.kv_cache import PagedKVPool, quantize_kv_int8
+from repro.serve.kv_cache import PagedKVPool, quantize_kv_int8, write_kv
 from repro.serve.telemetry import NULL_TRACER, Tracer
 
 __all__ = ["CachedDecoder", "sample_tokens"]
@@ -147,10 +147,19 @@ def _int8_roundtrip(x: jax.Array) -> jax.Array:
     return (q.astype(jnp.float32) * s[..., None]).astype(x.dtype)
 
 
-def _linear(p, cfg: ArchConfig, bias=None) -> Callable:
-    if bias is None:
-        return lambda x: L.apply_w(p, x, cfg)
-    return lambda x: L.apply_w(p, x, cfg) + bias
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class DenseLinear:
+    """One fp projection ``x @ w (+ b)`` as a pytree, so a jitted program
+    takes it as an argument like a :class:`QuantizedLinear`."""
+
+    w: Any
+    cfg: ArchConfig = dataclasses.field(metadata=dict(static=True))
+    b: Any = None
+
+    def __call__(self, x):
+        y = L.apply_w(self.w, x, self.cfg)
+        return y if self.b is None else y + self.b
 
 
 def _fp_blocks(params, cfg: ArchConfig) -> list[dict]:
@@ -160,15 +169,15 @@ def _fp_blocks(params, cfg: ArchConfig) -> list[dict]:
         blk = {
             "ln1": lp["ln1"],
             "ln2": lp["ln2"],
-            "attn.wq": _linear(at["wq"], cfg, at.get("bq")),
-            "attn.wk": _linear(at["wk"], cfg, at.get("bk")),
-            "attn.wv": _linear(at["wv"], cfg, at.get("bv")),
-            "attn.wo": _linear(at["wo"], cfg),
-            "mlp.wi": _linear(mp["wi"], cfg, mp.get("bi")),
-            "mlp.wo": _linear(mp["wo"], cfg, mp.get("bo")),
+            "attn.wq": DenseLinear(at["wq"], cfg, at.get("bq")),
+            "attn.wk": DenseLinear(at["wk"], cfg, at.get("bk")),
+            "attn.wv": DenseLinear(at["wv"], cfg, at.get("bv")),
+            "attn.wo": DenseLinear(at["wo"], cfg),
+            "mlp.wi": DenseLinear(mp["wi"], cfg, mp.get("bi")),
+            "mlp.wo": DenseLinear(mp["wo"], cfg, mp.get("bo")),
         }
         if cfg.mlp == "swiglu":
-            blk["mlp.wg"] = _linear(mp["wg"], cfg)
+            blk["mlp.wg"] = DenseLinear(mp["wg"], cfg)
         if cfg.qk_norm:
             blk["q_norm"] = at["q_norm"]
             blk["k_norm"] = at["k_norm"]
@@ -199,47 +208,52 @@ class CachedDecoder:
             raise ValueError(
                 f"serving adapter supports the dense family, got {self.cfg.family}"
             )
-        # blocks close over their params -> jit treats them as constants;
-        # one compile per (adapter, tokens/ctx shape) pair.
+        # every program takes the weights as its first argument (a
+        # pytree), never as closed-over constants: one compile per shape
+        # bucket, shared by every adapter with the same config.
         self._fwd = jax.jit(self._forward)
-        # fused decode: pool tensors are donated and updated in place by
-        # the trailing scatter — one dispatch per engine decode step.
-        self._fwd_paged = jax.jit(self._forward_paged, donate_argnums=(6, 7))
-        self._fwd_paged_q = jax.jit(
-            self._forward_paged_q, donate_argnums=(6, 7, 8, 9)
-        )
-        # fused batched prefill: same donation contract, one dispatch per
-        # engine prefill tick over the whole cross-request chunk batch.
-        self._fwd_prefill = jax.jit(
-            self._forward_prefill_paged, donate_argnums=(6, 7)
-        )
-        self._fwd_prefill_q = jax.jit(
-            self._forward_prefill_paged_q, donate_argnums=(6, 7, 8, 9)
-        )
-        # fused decode + on-device selection (non-speculative fast path);
-        # the trailing static bool picks the all-greedy argmax-only graph
-        self._fwd_paged_s = jax.jit(
-            self._forward_paged_sample, donate_argnums=(10, 11),
-            static_argnums=(12,),
-        )
-        self._fwd_paged_sq = jax.jit(
-            self._forward_paged_sample_q, donate_argnums=(10, 11, 12, 13),
-            static_argnums=(14,),
-        )
-        # fused speculative verify: prefill trunk + on-device selection +
-        # draft acceptance, one dispatch per engine verify tick
-        self._fwd_verify = jax.jit(
-            self._forward_verify, donate_argnums=(12, 13),
-            static_argnums=(14,),
-        )
-        self._fwd_verify_q = jax.jit(
-            self._forward_verify_q, donate_argnums=(12, 13, 14, 15),
-            static_argnums=(16,),
-        )
         # quality probe: dense teacher-forced forward + per-layer
         # activation reductions (serve/quality.py canaries); compiles
         # only if a canary actually runs
         self._fwd_probe = jax.jit(self._forward_probe)
+        self._jit_paged_programs()
+
+    def _jit_paged_programs(self, rep=None, kv=None):
+        """(Re)build the fused paged programs.  Each takes the pool arrays
+        ``(k, v, k_scale, v_scale)`` (scales None for fp pages) as one
+        donated argument and returns them updated in place by its trailing
+        scatter.  ``rep``/``kv`` optionally pin the output shardings of
+        replicated results and of the pool (distributed adapters)."""
+        pin = (lambda *o: {}) if rep is None else (
+            lambda *o: {"out_shardings": o}
+        )
+        # decode: one dispatch per engine decode step
+        self._fwd_paged = jax.jit(
+            self._forward_paged, donate_argnums=(7,), **pin(rep, kv)
+        )
+        # batched prefill: one dispatch per engine prefill tick over the
+        # whole cross-request chunk batch
+        self._fwd_prefill = jax.jit(
+            self._forward_prefill_paged, donate_argnums=(7,), **pin(rep, kv)
+        )
+        # decode + on-device selection (non-speculative fast path); the
+        # trailing static bool picks the all-greedy argmax-only graph
+        self._fwd_paged_s = jax.jit(
+            self._forward_paged_sample, donate_argnums=(11,),
+            static_argnums=(12,), **pin(rep, rep, kv),
+        )
+        # speculative verify: prefill trunk + on-device selection + draft
+        # acceptance, one dispatch per engine verify tick
+        self._fwd_verify = jax.jit(
+            self._forward_verify, donate_argnums=(13,),
+            static_argnums=(14,), **pin(rep, rep, rep, kv),
+        )
+
+    @property
+    def weights(self) -> dict:
+        """The pytree every jitted program takes as its first argument."""
+        return {"embed": self.embed, "final_norm": self.final_norm,
+                "blocks": self.blocks}
 
     # ---- constructors ---------------------------------------------------
 
@@ -302,22 +316,22 @@ class CachedDecoder:
         """
         if self.faults.rules:
             self.faults.check_dispatch()
-        logits, k_new, v_new = self._fwd(tokens, positions, ctx_k, ctx_v,
-                                         ctx_len)
+        logits, k_new, v_new = self._fwd(self.weights, tokens, positions,
+                                         ctx_k, ctx_v, ctx_len)
         if self.faults.rules:
             logits = _poison_lanes(logits, self.faults.nan_lanes())
         return logits, k_new, v_new
 
-    def _forward(self, tokens, positions, ctx_k, ctx_v, ctx_len):
+    def _forward(self, w, tokens, positions, ctx_k, ctx_v, ctx_len):
         cfg = self.cfg
-        x = L.embed(self.embed, tokens)
+        x = L.embed(w["embed"], tokens)
         new_k, new_v = [], []
-        for i, blk in enumerate(self.blocks):
+        for i, blk in enumerate(w["blocks"]):
             x, k, v = self._block(blk, x, positions, ctx_k[i], ctx_v[i], ctx_len)
             new_k.append(k)
             new_v.append(v)
-        x = L.norm_apply(self.final_norm, x, cfg)
-        logits = L.lm_logits(self.embed, x)
+        x = L.norm_apply(w["final_norm"], x, cfg)
+        logits = L.lm_logits(w["embed"], x)
         return logits, jnp.stack(new_k), jnp.stack(new_v)
 
     def _block(self, blk, x, positions, ck, cv, ctx_len):
@@ -381,7 +395,7 @@ class CachedDecoder:
         with self.tracer.span("dispatch:activation_probe",
                               lanes=B, tokens=S):
             logits, absmax, sat = self._fwd_probe(
-                jnp.asarray(padded), jnp.asarray(positions), ctx, ctx,
+                self.weights, jnp.asarray(padded), jnp.asarray(positions), ctx, ctx,
                 jnp.zeros((B,), jnp.int32), jnp.int32(S),
             )
         return np.asarray(logits[:, :S], np.float32), {
@@ -389,7 +403,7 @@ class CachedDecoder:
             "sat": np.asarray(sat, np.float64),
         }
 
-    def _forward_probe(self, tokens, positions, ctx_k, ctx_v, ctx_len,
+    def _forward_probe(self, w, tokens, positions, ctx_k, ctx_v, ctx_len,
                        n_valid):
         from repro.serve.quality import SAT_THRESHOLD
 
@@ -406,15 +420,15 @@ class CachedDecoder:
                 jnp.sum(ax >= SAT_THRESHOLD) / (n_el * x.shape[-1])
             )
 
-        x = L.embed(self.embed, tokens)
-        for i, blk in enumerate(self.blocks):
+        x = L.embed(w["embed"], tokens)
+        for i, blk in enumerate(w["blocks"]):
             reduce(x)
             x, _, _ = self._block(
                 blk, x, positions, ctx_k[i], ctx_v[i], ctx_len
             )
         reduce(x)
-        x = L.norm_apply(self.final_norm, x, cfg)
-        logits = L.lm_logits(self.embed, x)
+        x = L.norm_apply(w["final_norm"], x, cfg)
+        logits = L.lm_logits(w["embed"], x)
         return logits, jnp.stack(absmax), jnp.stack(sat)
 
     # ---- shared block pieces --------------------------------------------
@@ -483,61 +497,34 @@ class CachedDecoder:
                 np.asarray(ctx_len, np.int32),
                 np.asarray(pages, np.int32), np.asarray(offs, np.int32),
             ))
-            if pool.is_int8:
-                logits, pool.k, pool.v, pool.k_scale, pool.v_scale = (
-                    self._fwd_paged_q(
-                        *args, pool.k, pool.v, pool.k_scale, pool.v_scale
-                    )
-                )
-            else:
-                logits, pool.k, pool.v = self._fwd_paged(
-                    *args, pool.k, pool.v
-                )
+            logits, kv = self._fwd_paged(self.weights, *args, pool.arrays)
+            pool.arrays = kv
         if self.faults.rules:
             logits = _poison_lanes(logits, self.faults.nan_lanes())
         return logits
 
-    def _paged_trunk(self, tokens, positions, block_tables, ctx_len,
-                     pool_k, pool_v, k_scale, v_scale):
+    def _paged_trunk(self, w, tokens, positions, block_tables, ctx_len, kv):
         """Embed -> blocks (paged attention) -> logits; returns the new
         per-layer K/V stacked (L, B, KV, hd) for the trailing scatter."""
         cfg = self.cfg
-        x = L.embed(self.embed, tokens)  # (B, 1, D)
+        x = L.embed(w["embed"], tokens)  # (B, 1, D)
         new_k, new_v = [], []
-        for i, blk in enumerate(self.blocks):
+        for i, blk in enumerate(w["blocks"]):
             x, k, v = self._block_paged(
-                blk, x, positions, i, pool_k, pool_v, k_scale, v_scale,
-                block_tables, ctx_len,
+                blk, x, positions, i, kv, block_tables, ctx_len,
             )
             new_k.append(k)
             new_v.append(v)
-        x = L.norm_apply(self.final_norm, x, cfg)
-        logits = L.lm_logits(self.embed, x)
+        x = L.norm_apply(w["final_norm"], x, cfg)
+        logits = L.lm_logits(w["embed"], x)
         return logits, jnp.stack(new_k), jnp.stack(new_v)
 
-    def _forward_paged(self, tokens, positions, block_tables, ctx_len,
-                       pages, offs, pool_k, pool_v):
+    def _forward_paged(self, w, tokens, positions, block_tables, ctx_len,
+                       pages, offs, kv):
         logits, kn, vn = self._paged_trunk(
-            tokens, positions, block_tables, ctx_len, pool_k, pool_v,
-            None, None,
+            w, tokens, positions, block_tables, ctx_len, kv
         )
-        pool_k = pool_k.at[:, pages, offs].set(kn.astype(pool_k.dtype))
-        pool_v = pool_v.at[:, pages, offs].set(vn.astype(pool_v.dtype))
-        return logits, pool_k, pool_v
-
-    def _forward_paged_q(self, tokens, positions, block_tables, ctx_len,
-                         pages, offs, pool_k, pool_v, k_scale, v_scale):
-        logits, kn, vn = self._paged_trunk(
-            tokens, positions, block_tables, ctx_len, pool_k, pool_v,
-            k_scale, v_scale,
-        )
-        kq, ks = quantize_kv_int8(kn)
-        vq, vs = quantize_kv_int8(vn)
-        pool_k = pool_k.at[:, pages, offs].set(kq)
-        pool_v = pool_v.at[:, pages, offs].set(vq)
-        k_scale = k_scale.at[:, pages, offs].set(ks)
-        v_scale = v_scale.at[:, pages, offs].set(vs)
-        return logits, pool_k, pool_v, k_scale, v_scale
+        return logits, write_kv(*kv, pages, offs, kn, vn)
 
     # ---- fused decode + on-device selection -------------------------------
 
@@ -563,18 +550,10 @@ class CachedDecoder:
                 np.asarray(pages, np.int32), np.asarray(offs, np.int32),
                 *self._np_sampling(sampling),
             ))
-            greedy = self._all_greedy(sampling)
-            if pool.is_int8:
-                sel, logits, pool.k, pool.v, pool.k_scale, pool.v_scale = (
-                    self._fwd_paged_sq(
-                        *args, pool.k, pool.v, pool.k_scale, pool.v_scale,
-                        greedy,
-                    )
-                )
-            else:
-                sel, logits, pool.k, pool.v = self._fwd_paged_s(
-                    *args, pool.k, pool.v, greedy
-                )
+            sel, logits, kv = self._fwd_paged_s(
+                self.weights, *args, pool.arrays, self._all_greedy(sampling)
+            )
+            pool.arrays = kv
         if self.faults.rules:
             logits = _poison_lanes(logits, self.faults.nan_lanes())
         return sel, logits
@@ -593,44 +572,24 @@ class CachedDecoder:
         sub-graph entirely (one extra compile, reused every greedy step)."""
         return bool((np.asarray(sampling[0]) == 0.0).all())
 
-    def _forward_paged_sample(self, tokens, positions, block_tables,
+    def _forward_paged_sample(self, w, tokens, positions, block_tables,
                               ctx_len, pages, offs, temps, top_ps, seeds,
-                              draws, pool_k, pool_v, greedy_only=False):
+                              draws, kv, greedy_only=False):
         logits, kn, vn = self._paged_trunk(
-            tokens, positions, block_tables, ctx_len, pool_k, pool_v,
-            None, None,
+            w, tokens, positions, block_tables, ctx_len, kv
         )
         sel = sample_tokens(logits, temps, top_ps, seeds, draws, greedy_only)
-        pool_k = pool_k.at[:, pages, offs].set(kn.astype(pool_k.dtype))
-        pool_v = pool_v.at[:, pages, offs].set(vn.astype(pool_v.dtype))
-        return sel, logits, pool_k, pool_v
+        return sel, logits, write_kv(*kv, pages, offs, kn, vn)
 
-    def _forward_paged_sample_q(self, tokens, positions, block_tables,
-                                ctx_len, pages, offs, temps, top_ps, seeds,
-                                draws, pool_k, pool_v, k_scale, v_scale,
-                                greedy_only=False):
-        logits, kn, vn = self._paged_trunk(
-            tokens, positions, block_tables, ctx_len, pool_k, pool_v,
-            k_scale, v_scale,
-        )
-        sel = sample_tokens(logits, temps, top_ps, seeds, draws, greedy_only)
-        kq, ks = quantize_kv_int8(kn)
-        vq, vs = quantize_kv_int8(vn)
-        pool_k = pool_k.at[:, pages, offs].set(kq)
-        pool_v = pool_v.at[:, pages, offs].set(vq)
-        k_scale = k_scale.at[:, pages, offs].set(ks)
-        v_scale = v_scale.at[:, pages, offs].set(vs)
-        return sel, logits, pool_k, pool_v, k_scale, v_scale
-
-    def _block_paged(self, blk, x, positions, layer, pool_k, pool_v,
-                     k_scale, v_scale, block_tables, ctx_len):
+    def _block_paged(self, blk, x, positions, layer, kv, block_tables,
+                     ctx_len):
         cfg = self.cfg
         B = x.shape[0]
         h = L.norm_apply(blk["ln1"], x, cfg)
         q, k, v = self._qkv(blk, h, positions, kernel_proj=True)
         o = self._paged_attention(
-            q[:, 0], k[:, 0], v[:, 0], pool_k, pool_v, k_scale, v_scale,
-            block_tables, ctx_len, layer=layer,
+            q[:, 0], k[:, 0], v[:, 0], *kv, block_tables, ctx_len,
+            layer=layer,
         )
         o = o.astype(x.dtype).reshape(B, 1, cfg.q_dim)
         x = x + self._proj(blk, "attn.wo", o)
@@ -676,22 +635,14 @@ class CachedDecoder:
                 np.asarray(ctx_len, np.int32),
                 np.asarray(pages, np.int32), np.asarray(offs, np.int32),
             ))
-            if pool.is_int8:
-                logits, pool.k, pool.v, pool.k_scale, pool.v_scale = (
-                    self._fwd_prefill_q(
-                        *args, pool.k, pool.v, pool.k_scale, pool.v_scale
-                    )
-                )
-            else:
-                logits, pool.k, pool.v = self._fwd_prefill(
-                    *args, pool.k, pool.v
-                )
+            logits, kv = self._fwd_prefill(self.weights, *args, pool.arrays)
+            pool.arrays = kv
         if self.faults.rules:
             logits = _poison_lanes(logits, self.faults.nan_lanes())
         return logits
 
-    def _prefill_trunk(self, tokens, positions, block_tables, ctx_len,
-                       pool_k, pool_v, k_scale, v_scale, verify=False):
+    def _prefill_trunk(self, w, tokens, positions, block_tables, ctx_len,
+                       kv, verify=False):
         """Embed -> blocks (paged chunk attention) -> logits; returns the
         chunk's per-layer K/V stacked (L, B, C, KV, hd) for the scatter.
         ``verify`` marks the speculative verifier: attention goes through
@@ -700,44 +651,26 @@ class CachedDecoder:
         intra-chunk reads match what one-token decode would read back
         from the pool once the draft tokens are scattered."""
         cfg = self.cfg
-        x = L.embed(self.embed, tokens)  # (B, C, D)
+        x = L.embed(w["embed"], tokens)  # (B, C, D)
         new_k, new_v = [], []
-        for i, blk in enumerate(self.blocks):
+        for i, blk in enumerate(w["blocks"]):
             x, k, v = self._block_prefill_paged(
-                blk, x, positions, i, pool_k, pool_v, k_scale, v_scale,
-                block_tables, ctx_len, verify=verify,
+                blk, x, positions, i, kv, block_tables, ctx_len,
+                verify=verify,
             )
             new_k.append(k)
             new_v.append(v)
-        x = L.norm_apply(self.final_norm, x, cfg)
-        logits = L.lm_logits(self.embed, x)
+        x = L.norm_apply(w["final_norm"], x, cfg)
+        logits = L.lm_logits(w["embed"], x)
         return logits, jnp.stack(new_k), jnp.stack(new_v)
 
-    def _forward_prefill_paged(self, tokens, positions, block_tables,
-                               ctx_len, pages, offs, pool_k, pool_v):
+    def _forward_prefill_paged(self, w, tokens, positions, block_tables,
+                               ctx_len, pages, offs, kv):
         logits, kn, vn = self._prefill_trunk(
-            tokens, positions, block_tables, ctx_len, pool_k, pool_v,
-            None, None,
+            w, tokens, positions, block_tables, ctx_len, kv
         )
-        # kn/vn (L, B, C, KV, hd); pages/offs (B, C) broadcast together
-        pool_k = pool_k.at[:, pages, offs].set(kn.astype(pool_k.dtype))
-        pool_v = pool_v.at[:, pages, offs].set(vn.astype(pool_v.dtype))
-        return logits, pool_k, pool_v
-
-    def _forward_prefill_paged_q(self, tokens, positions, block_tables,
-                                 ctx_len, pages, offs, pool_k, pool_v,
-                                 k_scale, v_scale):
-        logits, kn, vn = self._prefill_trunk(
-            tokens, positions, block_tables, ctx_len, pool_k, pool_v,
-            k_scale, v_scale,
-        )
-        kq, ks = quantize_kv_int8(kn)
-        vq, vs = quantize_kv_int8(vn)
-        pool_k = pool_k.at[:, pages, offs].set(kq)
-        pool_v = pool_v.at[:, pages, offs].set(vq)
-        k_scale = k_scale.at[:, pages, offs].set(ks)
-        v_scale = v_scale.at[:, pages, offs].set(vs)
-        return logits, pool_k, pool_v, k_scale, v_scale
+        # kn/vn (L, B, C, KV, hd); pages/offs (B, C) address every token
+        return logits, write_kv(*kv, pages, offs, kn, vn)
 
     # ---- speculative draft-and-verify -------------------------------------
 
@@ -777,17 +710,10 @@ class CachedDecoder:
                 np.asarray(drafts, np.int32), np.asarray(n_drafts, np.int32),
                 *self._np_sampling(sampling),
             ))
-            greedy = self._all_greedy(sampling)
-            if pool.is_int8:
-                sel, n_acc, logits, pool.k, pool.v, pool.k_scale, \
-                    pool.v_scale = self._fwd_verify_q(
-                        *args, pool.k, pool.v, pool.k_scale, pool.v_scale,
-                        greedy,
-                    )
-            else:
-                sel, n_acc, logits, pool.k, pool.v = self._fwd_verify(
-                    *args, pool.k, pool.v, greedy
-                )
+            sel, n_acc, logits, kv = self._fwd_verify(
+                self.weights, *args, pool.arrays, self._all_greedy(sampling)
+            )
+            pool.arrays = kv
         if self.faults.rules:
             logits = _poison_lanes(logits, self.faults.nan_lanes())
         return sel, n_acc, logits
@@ -804,40 +730,18 @@ class CachedDecoder:
         )
         return jnp.cumprod(ok.astype(jnp.int32), axis=1).sum(axis=1)
 
-    def _forward_verify(self, tokens, positions, block_tables, ctx_len,
+    def _forward_verify(self, w, tokens, positions, block_tables, ctx_len,
                         pages, offs, drafts, n_drafts, temps, top_ps,
-                        seeds, draws, pool_k, pool_v, greedy_only=False):
+                        seeds, draws, kv, greedy_only=False):
         logits, kn, vn = self._prefill_trunk(
-            tokens, positions, block_tables, ctx_len, pool_k, pool_v,
-            None, None, verify=True,
+            w, tokens, positions, block_tables, ctx_len, kv, verify=True,
         )
         sel = sample_tokens(logits, temps, top_ps, seeds, draws, greedy_only)
         n_acc = self._accept(sel, drafts, n_drafts)
-        pool_k = pool_k.at[:, pages, offs].set(kn.astype(pool_k.dtype))
-        pool_v = pool_v.at[:, pages, offs].set(vn.astype(pool_v.dtype))
-        return sel, n_acc, logits, pool_k, pool_v
+        return sel, n_acc, logits, write_kv(*kv, pages, offs, kn, vn)
 
-    def _forward_verify_q(self, tokens, positions, block_tables, ctx_len,
-                          pages, offs, drafts, n_drafts, temps, top_ps,
-                          seeds, draws, pool_k, pool_v, k_scale, v_scale,
-                          greedy_only=False):
-        logits, kn, vn = self._prefill_trunk(
-            tokens, positions, block_tables, ctx_len, pool_k, pool_v,
-            k_scale, v_scale, verify=True,
-        )
-        sel = sample_tokens(logits, temps, top_ps, seeds, draws, greedy_only)
-        n_acc = self._accept(sel, drafts, n_drafts)
-        kq, ks = quantize_kv_int8(kn)
-        vq, vs = quantize_kv_int8(vn)
-        pool_k = pool_k.at[:, pages, offs].set(kq)
-        pool_v = pool_v.at[:, pages, offs].set(vq)
-        k_scale = k_scale.at[:, pages, offs].set(ks)
-        v_scale = v_scale.at[:, pages, offs].set(vs)
-        return sel, n_acc, logits, pool_k, pool_v, k_scale, v_scale
-
-    def _block_prefill_paged(self, blk, x, positions, layer, pool_k, pool_v,
-                             k_scale, v_scale, block_tables, ctx_len,
-                             verify=False):
+    def _block_prefill_paged(self, blk, x, positions, layer, kv,
+                             block_tables, ctx_len, verify=False):
         cfg = self.cfg
         B, C, _ = x.shape
         h = L.norm_apply(blk["ln1"], x, cfg)
@@ -848,12 +752,12 @@ class CachedDecoder:
         # DIAGONAL override (what one-token decode folds analytically for
         # the self position) — and is what gets scattered, quantized by
         # the pool exactly as one-token decode would
-        rt = verify and k_scale is not None
+        rt = verify and kv[2] is not None
         ka, va = (_int8_roundtrip(k), _int8_roundtrip(v)) if rt else (k, v)
         ks, vs = (k, v) if rt else (None, None)
         o = self._paged_prefill_attention(
-            q, ka, va, pool_k, pool_v, k_scale, v_scale, block_tables,
-            ctx_len, layer=layer, verify=verify, k_self=ks, v_self=vs,
+            q, ka, va, *kv, block_tables, ctx_len, layer=layer,
+            verify=verify, k_self=ks, v_self=vs,
         )
         o = o.astype(x.dtype).reshape(B, C, cfg.q_dim)
         x = x + self._proj(blk, "attn.wo", o)

@@ -16,7 +16,7 @@ Layers the single-device continuous-batching engine over a
     one psum after each row-parallel matmul (the Kronecker ``Uᵀ`` factor
     that follows mixes the output dim, so the sum cannot be deferred past
     it; see DESIGN.md §8);
-  * **the physical KV page pool** — ``(L, P, ps, KV, hd)`` splits on the
+  * **the physical KV page pool** — ``(L, P, KV, ps, hd)`` splits on the
     KV-head axis, NEVER on pages: every device owns the full page range
     for its local heads, so block-table indexing resolves locally and
     decode attention moves zero cross-device KV bytes;
@@ -35,19 +35,20 @@ whose KV-head count does not divide it, falls back to the replicated
 single-device math (the divisibility fallback in ``logical_to_pspec``),
 so the same engine code serves any mesh.
 
-CPU testing: ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-provides a multi-device host mesh; tests assert token-identical output
-vs the single-device engine (tests/test_distributed.py).
+CPU testing: ``jax.config.update("jax_num_cpu_devices", 8)`` before the
+backend starts provides a multi-device host mesh (tests/conftest.py);
+tests assert token-identical output vs the single-device engine
+(tests/test_distributed.py).
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.quantizer import QuantizedLinear
 from repro.kernels.paged_attention.ops import (
@@ -83,8 +84,8 @@ PACKED_AXES: dict[str, tuple] = {
     "mlp.wo": ("ff", None),
 }
 
-# Physical page pool (L, P, ps, KV, hd): shard KV heads, never pages.
-POOL_AXES: tuple = ("layers", "pages", None, "kv_heads", None)
+# Physical page pool (L, P, KV, ps, hd): shard KV heads, never pages.
+POOL_AXES: tuple = ("layers", "pages", "kv_heads", None, None)
 
 
 def make_serving_mesh(dp: int, mp: int) -> Mesh:
@@ -94,10 +95,14 @@ def make_serving_mesh(dp: int, mp: int) -> Mesh:
     if need > have:
         raise ValueError(
             f"mesh {dp}x{mp} needs {need} devices but only {have} are "
-            f"visible (on CPU: XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count={need})"
+            f"visible (on CPU: jax.config.update('jax_num_cpu_devices', "
+            f"{need}) before the backend starts)"
         )
-    return jax.make_mesh((dp, mp), ("data", "model"))
+    # Auto axes: GSPMD propagates shardings through the gathers and
+    # matmuls (Explicit axes, make_mesh's default, would demand an
+    # out-sharding on every op that mixes sharded operands)
+    return jax.make_mesh((dp, mp), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def _serving_ctx(
@@ -277,53 +282,15 @@ class DistributedCachedDecoder(CachedDecoder):
         pool = PagedKVPool(self.cfg, **kw)
         spec = self.ctx.pspec(POOL_AXES, pool.k.shape)
         kv_sh = NamedSharding(self.mesh, spec)
-        pool.k = jax.device_put(pool.k, kv_sh)
-        pool.v = jax.device_put(pool.v, kv_sh)
-        rep = self._rep
-        out_paged = (rep, kv_sh, kv_sh)
-        out_sample = (rep, rep, kv_sh, kv_sh)  # (sel, logits, k, v)
-        out_verify = (rep, rep, rep, kv_sh, kv_sh)  # (+ n_acc)
-        if pool.is_int8:
-            sc_sh = NamedSharding(self.mesh, P(*spec[:4]))
-            pool.k_scale = jax.device_put(pool.k_scale, sc_sh)
-            pool.v_scale = jax.device_put(pool.v_scale, sc_sh)
-            self._fwd_paged_q = jax.jit(
-                self._forward_paged_q,
-                donate_argnums=(6, 7, 8, 9),
-                out_shardings=(*out_paged, sc_sh, sc_sh),
-            )
-            self._fwd_prefill_q = jax.jit(
-                self._forward_prefill_paged_q,
-                donate_argnums=(6, 7, 8, 9),
-                out_shardings=(*out_paged, sc_sh, sc_sh),
-            )
-            self._fwd_paged_sq = jax.jit(
-                self._forward_paged_sample_q,
-                donate_argnums=(10, 11, 12, 13), static_argnums=(14,),
-                out_shardings=(*out_sample, sc_sh, sc_sh),
-            )
-            self._fwd_verify_q = jax.jit(
-                self._forward_verify_q,
-                donate_argnums=(12, 13, 14, 15), static_argnums=(16,),
-                out_shardings=(*out_verify, sc_sh, sc_sh),
-            )
-        self._fwd_paged = jax.jit(
-            self._forward_paged, donate_argnums=(6, 7),
-            out_shardings=out_paged,
+        sc_sh = NamedSharding(self.mesh, P(*spec[:4])) if pool.is_int8 \
+            else None
+        shardings = (kv_sh, kv_sh, sc_sh, sc_sh)
+        pool.arrays = tuple(
+            None if a is None else jax.device_put(a, sh)
+            for a, sh in zip(pool.arrays, shardings)
         )
-        self._fwd_prefill = jax.jit(
-            self._forward_prefill_paged, donate_argnums=(6, 7),
-            out_shardings=out_paged,
-        )
-        self._fwd_paged_s = jax.jit(
-            self._forward_paged_sample, donate_argnums=(10, 11),
-            static_argnums=(12,), out_shardings=out_sample,
-        )
-        self._fwd_verify = jax.jit(
-            self._forward_verify, donate_argnums=(12, 13),
-            static_argnums=(14,), out_shardings=out_verify,
-        )
-        self._pool_sharded = spec[3] is not None
+        self._jit_paged_programs(self._rep, shardings)
+        self._pool_sharded = spec[2] is not None
         return pool
 
     def _place(self, x, dtype=None):
@@ -333,6 +300,46 @@ class DistributedCachedDecoder(CachedDecoder):
     def _place_tree(self, arrays: tuple):
         """One batched device_put of a step's host arrays, replicated."""
         return jax.device_put(arrays, self._rep)
+
+    # ---- SPMD projections -----------------------------------------------
+
+    def _proj(self, blk, name, h):
+        """A sharded ``QuantizedLinear`` runs its packed matmul per shard
+        under ``shard_map``: a Pallas kernel cannot be partitioned by
+        GSPMD.  Column-parallel layers (codes split on the output dim)
+        return output-sharded ``z``; row-parallel layers (codes split on
+        the reduction rows) multiply their slice of ``h`` and ``psum`` in
+        float32 — the affine dequant correction is linear in ``h``, so
+        the shard sums are exact.  The incoherence transforms around the
+        matmul stay replicated (GSPMD gathers ``z`` for ``Uᵀ``)."""
+        f = blk[name]
+        if not isinstance(f, QuantizedLinear) or name not in PACKED_AXES:
+            return super()._proj(blk, name, h)
+        rows, cols = self.ctx.pspec(PACKED_AXES[name], f.packed.shape)
+        if rows is None and cols is None:
+            return super()._proj(blk, name, h)
+        from repro.kernels.quant_matmul.ops import quant_matmul
+
+        lead = (None,) * (h.ndim - 1)
+        maxq = f.state.maxq
+        if cols is not None:
+            def mm(hl, pk, s):
+                return quant_matmul(hl, pk, f.bits, f.n, s, maxq)
+
+            specs = (P(*lead, None), P(None, cols), P()), P(*lead, cols)
+        else:
+            axes = (rows,) if isinstance(rows, str) else rows
+            n_local = f.n // math.prod(self.mesh.shape[a] for a in axes)
+
+            def mm(hl, pk, s):
+                z = quant_matmul(hl.astype(jnp.float32), pk, f.bits,
+                                 n_local, s, maxq)
+                return jax.lax.psum(z, rows).astype(hl.dtype)
+
+            specs = (P(*lead, rows), P(rows, None), P()), P(*lead, None)
+        sharded = jax.shard_map(mm, mesh=self.mesh, in_specs=specs[0],
+                                out_specs=specs[1], check_vma=False)
+        return f(h, matmul=lambda hh: sharded(hh, f.packed, f.state.s))
 
     # ---- SPMD paged attention -------------------------------------------
 
@@ -349,7 +356,7 @@ class DistributedCachedDecoder(CachedDecoder):
                 block_tables, ctx_len, layer=layer,
             )
         h_spec = P(None, "model", None)
-        kv_spec = P(None, None, None, "model", None)
+        kv_spec = P(None, None, "model", None, None)
         interpret = self.paged_interpret
 
         if k_scale is None:
@@ -359,14 +366,14 @@ class DistributedCachedDecoder(CachedDecoder):
                     interpret=interpret,
                 )
 
-            f = shard_map(
+            f = jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(h_spec, h_spec, h_spec, kv_spec, kv_spec, P(), P()),
-                out_specs=h_spec, check_rep=False,
+                out_specs=h_spec, check_vma=False,
             )
             return f(q, k_new, v_new, pool_k, pool_v, block_tables, ctx_len)
 
-        sc_spec = P(None, None, None, "model")
+        sc_spec = P(None, None, "model", None)
 
         def local_q(q, kn, vn, kp, vp, ks, vs, bt, cl):
             return paged_gqa_decode(
@@ -374,11 +381,11 @@ class DistributedCachedDecoder(CachedDecoder):
                 v_scale=vs, interpret=interpret,
             )
 
-        f = shard_map(
+        f = jax.shard_map(
             local_q, mesh=self.mesh,
             in_specs=(h_spec, h_spec, h_spec, kv_spec, kv_spec, sc_spec,
                       sc_spec, P(), P()),
-            out_specs=h_spec, check_rep=False,
+            out_specs=h_spec, check_vma=False,
         )
         return f(q, k_new, v_new, pool_k, pool_v, k_scale, v_scale,
                  block_tables, ctx_len)
@@ -404,7 +411,7 @@ class DistributedCachedDecoder(CachedDecoder):
             )
         op = paged_gqa_verify if verify else paged_gqa_prefill
         h_spec = P(None, None, "model", None)  # (B, C, heads, hd)
-        kv_spec = P(None, None, None, "model", None)
+        kv_spec = P(None, None, "model", None, None)
         interpret = self.paged_interpret
 
         if k_scale is None:
@@ -414,14 +421,14 @@ class DistributedCachedDecoder(CachedDecoder):
                     interpret=interpret,
                 )
 
-            f = shard_map(
+            f = jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(h_spec, h_spec, h_spec, kv_spec, kv_spec, P(), P()),
-                out_specs=h_spec, check_rep=False,
+                out_specs=h_spec, check_vma=False,
             )
             return f(q, k_new, v_new, pool_k, pool_v, block_tables, ctx_len)
 
-        sc_spec = P(None, None, None, "model")
+        sc_spec = P(None, None, "model", None)
 
         if k_self is not None:
             # the self override shards like the chunk K/V (KV heads)
@@ -432,11 +439,11 @@ class DistributedCachedDecoder(CachedDecoder):
                     interpret=interpret,
                 )
 
-            f = shard_map(
+            f = jax.shard_map(
                 local_qs, mesh=self.mesh,
                 in_specs=(h_spec, h_spec, h_spec, kv_spec, kv_spec, sc_spec,
                           sc_spec, h_spec, h_spec, P(), P()),
-                out_specs=h_spec, check_rep=False,
+                out_specs=h_spec, check_vma=False,
             )
             return f(q, k_new, v_new, pool_k, pool_v, k_scale, v_scale,
                      k_self, v_self, block_tables, ctx_len)
@@ -447,11 +454,11 @@ class DistributedCachedDecoder(CachedDecoder):
                 v_scale=vs, interpret=interpret,
             )
 
-        f = shard_map(
+        f = jax.shard_map(
             local_q, mesh=self.mesh,
             in_specs=(h_spec, h_spec, h_spec, kv_spec, kv_spec, sc_spec,
                       sc_spec, P(), P()),
-            out_specs=h_spec, check_rep=False,
+            out_specs=h_spec, check_vma=False,
         )
         return f(q, k_new, v_new, pool_k, pool_v, k_scale, v_scale,
                  block_tables, ctx_len)

@@ -571,6 +571,16 @@ class Engine:
             self.cancel(r.rid)
         return victims
 
+    def fail_running(self, reason: str) -> list[Request]:
+        """Fail every request holding a lane — what a tick that raised
+        leaves in an unknown device state.  Returns them; pages are
+        released refcount-exactly and the next tick reports them."""
+        victims = list(self.running)
+        now = self.now()
+        for r in victims:
+            self._fail(r, reason, now)
+        return victims
+
     def set_speculative_k(self, k: int) -> int:
         """Clamp the LIVE speculative draft depth to ``k`` (degradation
         ladder hook).  Can only shrink below — or restore up to — the

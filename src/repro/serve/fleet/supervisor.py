@@ -29,6 +29,8 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import glob
+import os
 import signal
 import socket
 import subprocess
@@ -103,6 +105,32 @@ class ReplicaHandle:
         }
 
 
+def local_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device nodes
+    (``/dev/accel*``, or ``/dev/vfio/<n>`` under newer drivers) — without
+    loading the TPU runtime, which would take the chips from the
+    replicas this process is about to spawn.  0 on a host without."""
+    accel = glob.glob("/dev/accel[0-9]*")
+    if accel:
+        return len(accel)
+    return len([p for p in glob.glob("/dev/vfio/*")
+                if os.path.basename(p).isdigit()])
+
+
+def chip_env(chip: int, port: int) -> dict:
+    """libtpu variables that give one process exactly one chip of the
+    host: a single-process 1x1x1 slice made of chip ``chip``, with its
+    own runtime port so several such processes run side by side."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(port),
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "CLOUD_TPU_TASK_ID": "0",
+    }
+
+
 class ProcessReplicaFactory:
     """Spawn replicas as real ``launch/serve.py`` subprocesses.
 
@@ -112,15 +140,32 @@ class ProcessReplicaFactory:
     generation 0 only — per-replica chaos plans (``--replica-fault``)
     must not re-arm on the respawned process, or a ``replica_kill``
     would kill every incarnation and trip the circuit breaker by
-    design."""
+    design.
+
+    ``chips``: TPU chips on this host (:func:`local_tpu_chips`).  When
+    nonzero, replica ``i`` runs on chip ``i`` alone (:func:`chip_env`);
+    otherwise replicas inherit the parent's environment (CPU)."""
 
     def __init__(self, base_argv: list, *, host: str = "127.0.0.1",
                  first_spawn_args: Optional[dict] = None,
-                 echo: bool = True):
+                 echo: bool = True, chips: int = 0):
         self.base_argv = list(base_argv)
         self.host = host
         self.first_spawn_args = dict(first_spawn_args or {})
         self.echo = echo
+        self.chips = chips
+
+    def env(self, handle: ReplicaHandle) -> Optional[dict]:
+        """The replica's environment: the parent's, pinned to its own
+        chip on a TPU host (None = inherit unchanged)."""
+        if not self.chips:
+            return None
+        if handle.index >= self.chips:
+            raise ValueError(
+                f"replica {handle.index} has no chip of its own "
+                f"({self.chips} on this host)")
+        return {**os.environ,
+                **chip_env(handle.index, free_port(self.host))}
 
     def _pump(self, handle: ReplicaHandle, pipe) -> None:
         tag = f"[replica {handle.index}]"
@@ -137,7 +182,8 @@ class ProcessReplicaFactory:
         if handle.generation == 0:
             argv += self.first_spawn_args.get(handle.index, [])
         proc = subprocess.Popen(
-            argv, stdout=subprocess.PIPE, stderr=subprocess.STDOUT
+            argv, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+            env=self.env(handle),
         )
         handle.proc = proc
         handle.pid = proc.pid

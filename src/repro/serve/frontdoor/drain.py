@@ -38,6 +38,7 @@ class DrainReport:
     leaked_pages: int
     residual_slots: int
     served_total: int  # requests finished over the server's lifetime
+    tick_errors: int = 0  # ticks that raised (not injected faults)
 
     @property
     def clean(self) -> bool:
@@ -45,7 +46,7 @@ class DrainReport:
 
     @property
     def exit_code(self) -> int:
-        return 0 if self.clean else 1
+        return 0 if self.clean and not self.tick_errors else 1
 
     def lines(self) -> list:
         """Human-readable summary (the CLI prints these verbatim)."""
@@ -62,15 +63,19 @@ class DrainReport:
                 f"leak gate: FAILED ({self.leaked_pages} leaked pages, "
                 f"{self.residual_slots} mapped slots)"
             )
+        if self.tick_errors:
+            out.append(f"tick errors: {self.tick_errors} (requests in "
+                       f"those ticks failed; see the tracebacks above)")
         return out
 
 
 def capture(engine: "Engine", *, reason: str, t0: float, completed: int,
-            cancelled: int, deadline_hit: bool) -> DrainReport:
+            cancelled: int, deadline_hit: bool,
+            tick_errors: int = 0) -> DrainReport:
     leaked, slots = leak_gate(engine.pool)
     return DrainReport(
         reason=reason, duration_s=engine.now() - t0, completed=completed,
         cancelled=cancelled, deadline_hit=deadline_hit,
         leaked_pages=leaked, residual_slots=slots,
-        served_total=len(engine.finished),
+        served_total=len(engine.finished), tick_errors=tick_errors,
     )

@@ -29,13 +29,14 @@ import os
 import signal
 import threading
 import time
+import traceback
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import numpy as np
 
 from repro.serve.engine import Engine
-from repro.serve.faults import AdmissionRejected
+from repro.serve.faults import AdmissionRejected, FaultInjected
 from repro.serve.frontdoor import drain as drain_mod
 from repro.serve.frontdoor.admission import (
     GenerateParams,
@@ -105,6 +106,7 @@ class FrontDoor:
         self._drain_completed = 0
         self._drain_cancelled = 0
         self._drain_deadline_hit = False
+        self._tick_faults = 0  # ticks that raised outside a chaos drill
         self._started = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._thread_error: Optional[BaseException] = None
@@ -173,8 +175,18 @@ class FrontDoor:
                         self.metrics.inc("burst_rejected")
             try:
                 res = await self._call(self._tick_once)
-            except Exception:  # a tick must never wedge the loop
+            except FaultInjected:  # a chaos drill: count it, keep serving
                 self.metrics.inc("tick_errors")
+                await asyncio.sleep(self.idle_sleep_s)
+                continue
+            except Exception:
+                # a real fault (a kernel the compiler refused, a device
+                # error): say what it was, fail the lanes it hit, keep
+                # the server answering, and exit nonzero at drain
+                traceback.print_exc()
+                self.metrics.inc("tick_errors")
+                self._tick_faults += 1
+                await self._call(engine.fail_running, "tick_error")
                 await asyncio.sleep(self.idle_sleep_s)
                 continue
             self.streams.dispatch(res)
@@ -239,6 +251,7 @@ class FrontDoor:
             completed=self._drain_completed,
             cancelled=self._drain_cancelled,
             deadline_hit=self._drain_deadline_hit,
+            tick_errors=self._tick_faults,
         )
         return self.report
 

@@ -2,7 +2,11 @@
 
 Physical storage is one tensor per K/V of shape
 
-    (n_layers, n_pages, page_size, n_kv_heads, head_dim)
+    (n_layers, n_pages, n_kv_heads, page_size, head_dim)
+
+head-major within a page, so the paged-attention kernels read one head's
+``(page_size, head_dim)`` slice of a page as one whole tile (the TPU
+lowering's (8, 128) block rule; see ``kernels/paged_attention/kernel.py``).
 
 and each admitted sequence owns a *slot*: a row of a block table mapping
 logical page index -> physical page.  Pages are claimed lazily as the
@@ -19,7 +23,7 @@ Keys are stored post-RoPE, matching ``models.layers.cache_store``.
 Pages may be stored int8 (``dtype=jnp.int8``): values are quantized
 per-(token, head) on scatter (symmetric, scale = max|x|/127, matching
 ``models.layers._quantize_kv``) with fp32 scales in parallel
-``(L, P, ps, KV)`` tensors.  ``gather`` dequantizes; the paged-attention
+``(L, P, KV, ps)`` tensors.  ``gather`` dequantizes; the paged-attention
 kernel reads the int8 pages + scales directly (1 byte/elem of KV traffic).
 
 Prefix caching (``prefix_cache=True``): every physical page carries a
@@ -50,7 +54,8 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.serve.faults import NO_FAULTS
 
-__all__ = ["PagedKVPool", "pages_needed", "quantize_kv_int8"]
+__all__ = ["PagedKVPool", "pages_needed", "quantize_kv_int8", "write_pages",
+           "write_kv"]
 
 
 def pages_needed(n_tokens: int, page_size: int) -> int:
@@ -70,11 +75,35 @@ def page_bucket(n_pages: int, cap: int) -> int:
     return min(b, cap)
 
 
+def write_pages(phys: jax.Array, pages: jax.Array, offs: jax.Array,
+                vals: jax.Array) -> jax.Array:
+    """Store ``vals`` (L, *idx, KV[, hd]) at the physical (page, offset)
+    addresses ``pages``/``offs`` (each of shape idx) of a head-major pool
+    (L, P, KV, ps[, hd]) — page tensors and their scales alike."""
+    return phys.at[:, pages, :, offs].set(
+        jnp.moveaxis(vals, 0, pages.ndim).astype(phys.dtype)
+    )
+
+
+def write_kv(k, v, k_scale, v_scale, pages, offs, k_new, v_new):
+    """Scatter new K/V (L, *idx, KV, hd) into pool arrays; int8 pools
+    (``k_scale`` not None) quantize per-(token, head) on the way in.
+    Returns the updated ``(k, v, k_scale, v_scale)``."""
+    if k_scale is None:
+        return (write_pages(k, pages, offs, k_new),
+                write_pages(v, pages, offs, v_new), None, None)
+    kq, ks = quantize_kv_int8(k_new)
+    vq, vs = quantize_kv_int8(v_new)
+    return (write_pages(k, pages, offs, kq), write_pages(v, pages, offs, vq),
+            write_pages(k_scale, pages, offs, ks),
+            write_pages(v_scale, pages, offs, vs))
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter(phys: jax.Array, pages: jax.Array, offs: jax.Array,
              vals: jax.Array) -> jax.Array:
-    """phys (L, P, ps, KV, hd); pages/offs (T,); vals (L, T, KV, hd)."""
-    return phys.at[:, pages, offs].set(vals.astype(phys.dtype))
+    """phys (L, P, KV, ps, hd); pages/offs (T,); vals (L, T, KV, hd)."""
+    return write_pages(phys, pages, offs, vals)
 
 
 def quantize_kv_int8(vals: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -94,7 +123,8 @@ def _scatter_q(phys: jax.Array, scales: jax.Array, pages: jax.Array,
                offs: jax.Array, vals: jax.Array):
     """int8 variant: quantize vals (L, T, KV, hd), store values + scales."""
     q, sc = quantize_kv_int8(vals)
-    return phys.at[:, pages, offs].set(q), scales.at[:, pages, offs].set(sc)
+    return (write_pages(phys, pages, offs, q),
+            write_pages(scales, pages, offs, sc))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -103,13 +133,17 @@ def _copy_page(phys: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
     return phys.at[:, dst].set(phys[:, src])
 
 
+def _token_major(g: jax.Array) -> jax.Array:
+    """(L, B, Pmax, KV, ps, hd) -> (L, B, Pmax*ps, KV, hd)."""
+    L, B, _, KV, _, hd = g.shape
+    return g.transpose(0, 1, 2, 4, 3, 5).reshape(L, B, -1, KV, hd)
+
+
 @jax.jit
 def _gather(phys: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """phys (L, P, ps, KV, hd), block_tables (B, Pmax) ->
+    """phys (L, P, KV, ps, hd), block_tables (B, Pmax) ->
     (L, B, Pmax*ps, KV, hd) contiguous per-sequence windows."""
-    g = phys[:, block_tables]  # (L, B, Pmax, ps, KV, hd)
-    L, B = g.shape[0], g.shape[1]
-    return g.reshape(L, B, -1, *phys.shape[-2:])
+    return _token_major(phys[:, block_tables])
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))
@@ -118,10 +152,7 @@ def _gather_q(phys: jax.Array, scales: jax.Array, block_tables: jax.Array,
     """int8 variant of :func:`_gather`: dequantize to ``dtype``."""
     g = phys[:, block_tables].astype(jnp.float32)
     s = scales[:, block_tables]
-    L, B = g.shape[0], g.shape[1]
-    return (g * s[..., None]).astype(dtype).reshape(
-        L, B, -1, *phys.shape[-2:]
-    )
+    return _token_major((g * s[..., None]).astype(dtype))
 
 
 @dataclasses.dataclass
@@ -169,7 +200,7 @@ class PagedKVPool:
         dt = jnp.dtype(dtype) if dtype is not None else jnp.dtype(cfg.dtype)
         # fp dtype handed out by gather (and used for dequantized int8 reads)
         self._fp_dtype = jnp.dtype(cfg.dtype) if dt == jnp.int8 else dt
-        shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
         self.k = jnp.zeros(shape, dt)
         self.v = jnp.zeros(shape, dt)
         if dt == jnp.int8:
@@ -488,6 +519,17 @@ class PagedKVPool:
             "cow_copies": lambda: self.cow_copies,
             "prefix_hit_pages": lambda: self.prefix_hit_pages,
         }
+
+    @property
+    def arrays(self) -> tuple:
+        """``(k, v, k_scale, v_scale)`` — the device state a fused serving
+        program takes (donated) and returns updated; scales are None for
+        fp pages."""
+        return self.k, self.v, self.k_scale, self.v_scale
+
+    @arrays.setter
+    def arrays(self, kv: tuple) -> None:
+        self.k, self.v, self.k_scale, self.v_scale = kv
 
     def _storage(self) -> list:
         arrs = [self.k, self.v]

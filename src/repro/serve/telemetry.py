@@ -54,6 +54,7 @@ import time
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 __all__ = [
     "Counter",
@@ -66,11 +67,6 @@ __all__ = [
     "phase_breakdown",
     "validate_chrome_trace",
 ]
-
-try:  # optional: lines engine spans up with XLA profiler timelines
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover - profiler API unavailable
-    _TraceAnnotation = None
 
 
 # ---------------------------------------------------------------------------
@@ -111,7 +107,7 @@ class _SpanHandle:
         tr = self._tracer
         if tr.sync:
             tr._sync()
-        if tr.annotate and _TraceAnnotation is not None:
+        if tr.annotate:
             self._ann = _TraceAnnotation(self._name)
             self._ann.__enter__()
         self._depth = tr._depth

@@ -1,7 +1,9 @@
 """Shared fixtures/helpers for the QuIP repro test suite.
 
-IMPORTANT: no XLA_FLAGS device-count override here — unit/smoke tests run on
-the single real CPU device.  Only launch/dryrun.py fakes 512 devices.
+The CPU backend is split into 8 virtual devices before any backend starts,
+so the tensor-parallel serving tests (test_distributed.py, the TP cases of
+test_chaos.py) build real meshes.  Single-device code is unaffected: it
+places everything on ``jax.devices()[0]``.
 """
 from __future__ import annotations
 
@@ -9,6 +11,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+jax.config.update("jax_num_cpu_devices", 8)
 
 
 def make_weights(

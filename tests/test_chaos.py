@@ -197,7 +197,7 @@ def test_quantized_path_fault_quarantine():
 @pytest.mark.skipif(
     jax.device_count() < 2,
     reason="needs a multi-device host "
-           "(XLA_FLAGS=--xla_force_host_platform_device_count=8)",
+           "(jax_num_cpu_devices, conftest.py)",
 )
 def test_tp_engine_fault_quarantine(fp_ctx, baseline):
     """TP parity under faults: cancel + NaN quarantine on a 2-way model

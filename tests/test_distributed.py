@@ -1,8 +1,8 @@
 """Tensor-parallel serving tests (serve/distributed.py).
 
-Need a multi-device host: run under
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (scripts/ci.sh
-does); on the default single-device CPU the whole module skips.
+Need a multi-device host: tests/conftest.py splits the CPU into 8
+virtual devices before the backend starts; with a single device the
+whole module skips.
 Everything asserts TOKEN-IDENTICAL behavior vs the single-device engine —
 sharding is a layout choice, never a numerics choice.
 """
@@ -35,8 +35,7 @@ from repro.runtime.sharding import MeshContext, serving_rules
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 2,
-    reason="needs a multi-device host "
-           "(XLA_FLAGS=--xla_force_host_platform_device_count=8)",
+    reason="needs a multi-device host (jax_num_cpu_devices, conftest.py)",
 )
 
 
@@ -173,7 +172,7 @@ def test_sharded_pool_accounting_and_roundtrip(mesh, dtype):
     mp = mesh.shape["model"]
     assert p1.total_bytes() == p0.total_bytes()
     assert p1.device_bytes() == p0.total_bytes() // mp
-    assert tuple(p1.k.sharding.spec) == (None, None, None, "model", None)
+    assert tuple(p1.k.sharding.spec) == (None, None, "model", None, None)
     # identical admit/extend/evict decisions
     for pool in (p0, p1):
         a = pool.admit(5)

@@ -142,3 +142,60 @@ def test_quantize_artifact_then_serve(tmp_path):
         "--load-quantized", str(tmp_path / "art"), "--check",
     ])
     assert rc == 0
+
+
+def test_random_artifact_serves_ragged_prompts(tmp_path):
+    """A seeded random 2-bit artifact (how the published-width bring-up
+    gets weights, here at smoke width) loads through the unchanged
+    ``load_quantized`` and serves ragged prompts on the paged path;
+    ``--check`` holds the engine to the kernel-free quantized recompute
+    oracle, one batch per prompt length."""
+    from repro.configs import get_smoke_config
+    from repro.core.quantizer import QuantizedLinear
+    from repro.serve.artifacts import load_quantized, save_random_quantized
+
+    cfg = get_smoke_config("qwen3-14b")
+    save_random_quantized(tmp_path, cfg, seed=1)
+    qm, _ = load_quantized(tmp_path)
+    assert len(qm.blocks) == cfg.n_layers
+    lins = [v for blk in qm.reference().blocks for v in blk.values()
+            if isinstance(v, QuantizedLinear)]
+    assert lins and not any(q.use_kernel for q in lins)
+    seen = {}
+    rc = sv.main([
+        "--load-quantized", str(tmp_path), "--paged", "--paged-prefill",
+        "--requests", "3", "--prompt-len", "5,19", "--gen", "4",
+        "--arrival-gap", "0", "--check",
+    ], on_done=lambda eng, sub: seen.update(reqs=[r for _, r in sub]))
+    assert rc == 0
+    assert [len(r.prompt) for r in seen["reqs"]] == [5, 19, 5]
+    for r in seen["reqs"]:
+        logits = np.stack(r.step_logits)
+        assert logits.shape == (4, cfg.vocab) and np.isfinite(logits).all()
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """The persistent compile cache lives where ``JAX_COMPILATION_CACHE_DIR``
+    says (JAX reads the variable; the helper sets nothing), and otherwise
+    in one fixed directory of the checkout, the same on every call."""
+    import pathlib
+
+    from repro.runtime import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert compile_cache.use_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            d = compile_cache.use_compile_cache()
+            assert d == compile_cache.use_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == d
+            root = pathlib.Path(d).parent
+            assert pathlib.Path(d).name == ".jax_cache"
+            assert (root / "src" / "repro" / "runtime").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

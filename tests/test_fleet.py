@@ -31,6 +31,8 @@ from repro.serve import CachedDecoder, Engine, EngineConfig
 from repro.serve.faults import parse_fault_plan
 from repro.serve.fleet import (
     FleetRouter,
+    ProcessReplicaFactory,
+    ReplicaHandle,
     RequestJournal,
     Supervisor,
     prefix_key,
@@ -494,3 +496,23 @@ def test_supervisor_restarts_crashed_replica(fp_stack):
         report = router.drain_and_join()
     assert report.exit_code == 0
     assert report.replicas[0]["restarts"] == 2
+
+
+def test_replicas_get_one_chip_each_on_a_tpu_host(monkeypatch):
+    """On a TPU host every replica process is pinned to its own chip
+    through libtpu's environment, and a fleet larger than the host's
+    chips is refused before anything starts."""
+    from repro.launch import serve
+    from repro.serve.fleet import supervisor
+
+    f = ProcessReplicaFactory(["true"], chips=4)
+    envs = [f.env(ReplicaHandle(i, "127.0.0.1")) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    with pytest.raises(ValueError, match="no chip"):
+        f.env(ReplicaHandle(4, "127.0.0.1"))
+    assert ProcessReplicaFactory(["true"]).env(ReplicaHandle(0, "h")) is None
+    monkeypatch.setattr(supervisor, "local_tpu_chips", lambda: 2)
+    with pytest.raises(SystemExit, match="this host has 2"):
+        serve.main(["--fleet", "3"])

@@ -578,3 +578,34 @@ def test_parse_generate_body_validation():
     ):
         with pytest.raises(ValueError):
             parse_generate_body(json.dumps(bad).encode())
+
+
+def test_tick_error_fails_its_requests_and_exits_nonzero(fp_stack, capfd):
+    """A tick that raises (a kernel the compiler refused, a device fault)
+    is not swallowed: the traceback is printed, the requests holding
+    lanes fail with ``tick_error``, the server keeps answering, and the
+    drain report exits nonzero."""
+    cfg, model, params, prompts = fp_stack
+    eng = _engine(model, params)
+    real_tick, calls = eng.tick, []
+
+    def tick():
+        if eng.running and not calls:
+            calls.append(1)
+            raise RuntimeError("refused by the compiler")
+        return real_tick()
+
+    eng.tick = tick
+    fd = FrontDoor(eng, drain_timeout_s=5.0).start_in_thread()
+    try:
+        p = [int(t) for t in prompts[0]]
+        c, r = _post(fd.port, {"prompt": p, "max_new": 4, "stream": False})
+        body = json.loads(r.read())
+        c.close()
+        assert body["finish_reason"] == "tick_error"
+        assert _gen_tokens(fd.port, p, 4)  # the next request is served
+    finally:
+        report = fd.drain_and_join()
+    assert calls and report.tick_errors == 1
+    assert report.clean and report.exit_code == 1
+    assert "refused by the compiler" in capfd.readouterr().err

@@ -47,10 +47,8 @@ def test_optq_equals_ldlq_bit_exact(bits):
     implementations are algebraically identical but follow different fp op
     orders, so fp32 can flip ties at a rounding boundary and the feedback
     then legitimately amplifies the flip downstream (measured below)."""
-    from jax.experimental import enable_x64
-
     maxq = 2**bits - 1
-    with enable_x64():
+    with jax.enable_x64(True):
         W = (
             jax.random.uniform(jax.random.PRNGKey(0), (100, 100)) * maxq
         ).astype(jnp.float64)

@@ -26,13 +26,13 @@ def _setup(
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     H = KV * G
     if int8:
-        kp = jax.random.randint(ks[0], (L, P, ps, KV, hd), -127, 128, jnp.int8)
-        vp = jax.random.randint(ks[1], (L, P, ps, KV, hd), -127, 128, jnp.int8)
-        k_sc = jnp.abs(jax.random.normal(ks[4], (L, P, ps, KV))) * 0.02 + 1e-3
-        v_sc = jnp.abs(jax.random.normal(ks[5], (L, P, ps, KV))) * 0.02 + 1e-3
+        kp = jax.random.randint(ks[0], (L, P, KV, ps, hd), -127, 128, jnp.int8)
+        vp = jax.random.randint(ks[1], (L, P, KV, ps, hd), -127, 128, jnp.int8)
+        k_sc = jnp.abs(jax.random.normal(ks[4], (L, P, KV, ps))) * 0.02 + 1e-3
+        v_sc = jnp.abs(jax.random.normal(ks[5], (L, P, KV, ps))) * 0.02 + 1e-3
     else:
-        kp = jax.random.normal(ks[0], (L, P, ps, KV, hd), jnp.float32)
-        vp = jax.random.normal(ks[1], (L, P, ps, KV, hd), jnp.float32)
+        kp = jax.random.normal(ks[0], (L, P, KV, ps, hd), jnp.float32)
+        vp = jax.random.normal(ks[1], (L, P, KV, ps, hd), jnp.float32)
         k_sc = v_sc = None
     q = jax.random.normal(ks[2], (B, H, hd), jnp.float32)
     kn = jax.random.normal(ks[3], (B, KV, hd), jnp.float32) * 0.5

@@ -801,6 +801,16 @@ def _spec_prompts(n=3, reps=8):
     return np.tile(np.asarray([7, 91, 33, 150], np.int32), (n, reps))
 
 
+def _spec_prompts_mixed():
+    """Three different cycles: the model follows some and breaks others,
+    so a speculative run both accepts drafts and rolls rejected ones back
+    (on one repeated cycle every draft can land, and nothing rolls back)."""
+    return np.stack([
+        np.tile([7, 91, 33, 150], 8), np.tile([3, 3, 9, 9], 8),
+        np.tile([12, 40, 12, 41], 8),
+    ]).astype(np.int32)
+
+
 def test_drafter_ngram_proposals():
     from repro.serve.drafter import NgramDrafter, make_drafter
 
@@ -943,7 +953,7 @@ def test_engine_speculative_fp_token_and_logits_parity():
     cfg = _smoke_cfg()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    prompts = _spec_prompts()
+    prompts = _spec_prompts_mixed()
     gen = 12
     engine, reqs = _run_engine(
         CachedDecoder.from_model(model, params), prompts, gen,
@@ -1284,3 +1294,43 @@ def test_device_sampling_survives_eviction_replay():
     assert engine.stats["evictions"] > 0
     for a, b in zip(calm, pressured):
         np.testing.assert_array_equal(a, b)
+
+
+def _literal_sizes(hlo_text: str) -> list:
+    """Element counts of the non-splat ``dense<...>`` constants in a
+    lowered program (a splat — one repeated value — carries no data)."""
+    import math
+    import re
+
+    sizes = []
+    for body, dims in re.findall(
+        r"dense<(\[|\"0x)[^>]*> : tensor<((?:\d+x)*)", hlo_text
+    ):
+        sizes.append(math.prod(int(d) for d in dims.split("x") if d))
+    return sizes
+
+
+def test_decode_program_takes_weights_as_arguments(quantized_smoke):
+    """No serving program embeds weight arrays as constants: the weights
+    are the program's first argument.  Weights compiled in as literals
+    would not fit a protobuf (2 GB) or the chip's memory at full width,
+    and every adapter would compile its own copy."""
+    cfg, qm, _ = quantized_smoke
+    ad = CachedDecoder.from_quantized(qm)
+    pool = ad.make_pool(n_pages=5, page_size=4, n_slots=2,
+                        max_pages_per_seq=2)
+    z = lambda *s: np.zeros(s, np.int32)
+    B = 2
+    text = ad._fwd_paged_s.lower(
+        ad.weights, z(B, 1), z(B, 1), z(B, 2), z(B), z(B), z(B),
+        np.zeros(B, np.float32), np.ones(B, np.float32), z(B), z(B),
+        pool.arrays, True,
+    ).as_text()
+    assert "quant_matmul" in text or "dot_general" in text
+    smallest_weight = cfg.head_dim  # the q/k norm scales
+    assert max(_literal_sizes(text), default=0) < smallest_weight
+    # the same check catches a closure: a constant-folded weight shows up
+    baked = jax.jit(lambda x: x @ qm.blocks[0]["attn.wq"].dequantize().T)
+    assert max(_literal_sizes(baked.lower(np.zeros((1, cfg.d_model),
+                                                   np.float32)).as_text())
+               ) >= smallest_weight
