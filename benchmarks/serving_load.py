@@ -192,13 +192,7 @@ def run_fleet(args, cfg, prompts, lengths, arrivals):
     if args.quantize:
         replica_argv += ["--bits", str(args.bits)]
 
-    from repro.serve.fleet.supervisor import local_tpu_chips
-
-    chips = local_tpu_chips()
-    if chips and args.fleet > chips:
-        raise SystemExit(f"--fleet {args.fleet}: each replica needs a TPU "
-                         f"chip of its own and this host has {chips}")
-    factory = ProcessReplicaFactory(replica_argv, chips=chips)
+    factory = ProcessReplicaFactory(replica_argv)
     sup = Supervisor(factory, args.fleet, probe_interval_s=0.25,
                      start_timeout_s=600.0,
                      replica_drain_timeout_s=args.drain_timeout_s + 30.0)

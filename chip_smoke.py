@@ -13,10 +13,12 @@ Pallas kernel, no page pool).  It also checks that the compiled serving
 programs hold the Pallas kernels.
 
 Four chips (depth cut to ``FOUR_CHIP_LAYERS``; widths stay published):
-the parent process never touches JAX.  One child compares a (1, 4)
-tensor-parallel engine with the one-chip engine on the same artifact; a
-``--fleet 4`` server then answers the same requests, one replica per
-chip, and its streams must be token-identical to the one-chip engine.
+the parent process never touches JAX.  One child holds a (1, 4)
+tensor-parallel engine and the one-chip engine on the same artifact to
+the float32 reference, the TP engine within a small multiple of the
+one-chip engine's error; a ``--fleet 4`` server then answers the same
+requests, one replica per chip, and its streams must be token-identical
+to the one-chip engine.
 
 The last line of standard output is the JSON verdict, printed only when
 every check passed.  Without a TPU the script exits nonzero.
@@ -27,6 +29,7 @@ import argparse
 import json
 import os
 import shutil
+import signal
 import subprocess
 import sys
 import tempfile
@@ -53,14 +56,29 @@ ENGINE_FLAGS = [
 # engine computes in bfloat16 (unit roundoff 2^-9): every linear, norm and
 # attention output is rounded, 40 layers deep, and the logits themselves
 # are bf16.  Against a float32 reference that leaves a relative RMS error
-# of a few percent; a wrong kernel (bad unpack, wrong page, wrong scale)
-# moves logits by their own size, 20x past these bounds.
+# of a few percent (v5e, full width: 2.84% RMS, 15.7% max).  Faults
+# planted in a CPU rehearsal (interpret-mode kernels; KV 8, head_dim 128,
+# d_model 1024, 4 layers; sound: 0.63% / 3.4%, int8 pages 1.42% / 7.6%)
+# read, as RMS / max: one block-table entry off by one page 48% / 388%;
+# k and v scales of int8 pages swapped 33% / 246%; the 2-bit unpack
+# shifted by one bit 141% / 665% -- each 6x or more past these bounds.
 TOL_REL_RMS = 0.05
 TOL_REL_MAX = 0.5
+# The TP engine does the one-chip engine's bf16 arithmetic with the
+# reductions split four ways, so against the same float32 reference its
+# error should be of the same size; it may reach this multiple of the
+# one-chip engine's reading, measured in the same run.  On four v5e
+# chips the sound TP engine read 1.002x (RMS) and 1.007x (max); in a CPU
+# rehearsal at reduced width it read 0.999x, while one shard's
+# row-parallel partial sum 2% off read 3.9x and a bf16 psum 1.31x.
+TP_VS_ONE_CHIP = 1.25
+# seconds the whole four-chip path may take before it gives up
+FOUR_CHIP_BUDGET_S = 480
+T0 = time.perf_counter()
 
 
 def log(msg: str) -> None:
-    print(f"[chip_smoke] {msg}", flush=True)
+    print(f"[chip_smoke {time.perf_counter() - T0:6.1f}s] {msg}", flush=True)
 
 
 def verdict(kind: str, count: int) -> str:
@@ -218,7 +236,9 @@ def reference_logits(adapter, reqs) -> list:
     return [np.asarray(l, np.float32) for l in logits]
 
 
-def compare_logits(got: list, want: list, label: str) -> dict:
+def compare_logits(got: list, want: list, label: str,
+                   tol_rms: float = TOL_REL_RMS,
+                   tol_max: float = TOL_REL_MAX) -> dict:
     """Relative errors of ``got`` vs ``want`` (lists of (T, V) arrays) and
     greedy agreement; raises when out of tolerance or not finite."""
     g = np.concatenate(got).astype(np.float64)
@@ -229,43 +249,17 @@ def compare_logits(got: list, want: list, label: str) -> dict:
     rel_rms = float(np.sqrt(np.mean((g - w) ** 2))) / scale
     rel_max = float(np.max(np.abs(g - w))) / scale
     agree = float(np.mean(np.argmax(g, -1) == np.argmax(w, -1)))
-    log(f"{label}: rel_rms_err={rel_rms:.6g} (tol {TOL_REL_RMS}) "
-        f"rel_max_err={rel_max:.6g} (tol {TOL_REL_MAX}) "
+    log(f"{label}: rel_rms_err={rel_rms:.6g} (tol {tol_rms:.6g}) "
+        f"rel_max_err={rel_max:.6g} (tol {tol_max:.6g}) "
         f"ref_logit_rms={scale:.6g} greedy_agreement={agree:.6g} "
         f"over {g.shape[0]} positions, finite=True")
-    if rel_rms > TOL_REL_RMS or rel_max > TOL_REL_MAX:
+    if rel_rms > tol_rms or rel_max > tol_max:
         raise SystemExit(f"{label}: logits out of tolerance")
     return {"rel_rms": rel_rms, "rel_max": rel_max, "agree": agree}
 
 
-def compare_streams(got_reqs, want_reqs, label: str) -> None:
-    """Compare two engines that each chose their own tokens.  Logits are
-    compared up to and including each request's first emission whose
-    tokens differ: past it the engines continue different sequences.  A
-    divergence is accepted only at a near-tie that the measured logit
-    error at that emission explains (the two tokens' reference logits lie
-    closer than twice that error)."""
-    got, want, splits = [], [], []
-    for g, w in zip(got_reqs, want_reqs):
-        gl, wl = np.stack(g.step_logits), np.stack(w.step_logits)
-        d = next((i for i, (a, b) in enumerate(zip(g.out_tokens,
-                                                   w.out_tokens)) if a != b),
-                 None)
-        n = len(wl) if d is None else d + 1
-        got.append(gl[:n])
-        want.append(wl[:n])
-        if d is not None:
-            gap = float(wl[d, w.out_tokens[d]] - wl[d, g.out_tokens[d]])
-            err = float(np.max(np.abs(gl[d].astype(np.float64) - wl[d])))
-            splits.append((d, gap, err))
-            if gap > 2 * err:
-                raise SystemExit(
-                    f"{label}: tokens differ at emission {d} where the "
-                    f"reference prefers its token by {gap:.4g}, more than "
-                    f"twice the logit error there ({err:.4g})")
-    log(f"{label}: streams split at near-ties (emission, reference gap, "
-        f"max logit error) {splits}")
-    compare_logits(got, want, label)
+def engine_logits(reqs) -> list:
+    return [np.stack(r.step_logits) for r in reqs]
 
 
 # ---------------------------------------------------------------------------
@@ -312,7 +306,7 @@ def one_chip() -> int:
         want = reference_logits(eng.adapter, reqs)
         log(f"reference: float32 forward over {len(reqs)} sequences in "
             f"{time.perf_counter() - t1:.1f}s")
-        compare_logits([np.stack(r.step_logits) for r in reqs], want,
+        compare_logits(engine_logits(reqs), want,
                        "engine vs float32 reference")
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
@@ -327,9 +321,11 @@ def one_chip() -> int:
 
 def tp_child(directory: str) -> int:
     """Child: the one-chip engine and the (1, 4) TP engine serve the same
-    requests one at a time (the shapes a lone fleet replica compiles);
-    their logits must agree within tolerance.  Writes the one-chip token
-    streams for the fleet step."""
+    requests one at a time (the shapes a lone fleet replica compiles).
+    Each is held to the float32 reference, teacher-forced on its own
+    tokens; the TP engine may stray at most ``TP_VS_ONE_CHIP`` times as
+    far as the one-chip engine does.  Writes the one-chip token streams
+    for the fleet step."""
     import types
 
     import jax
@@ -365,8 +361,9 @@ def tp_child(directory: str) -> int:
 
     with jax.default_device(devs[0]):
         qm, _ = load_quantized(directory)
-        one, _ = serve_one_by_one(CachedDecoder.from_quantized(qm))
-    del qm
+        one_adapter = CachedDecoder.from_quantized(qm)
+        one, _ = serve_one_by_one(one_adapter)
+    log(f"one-chip engine served {len(one)} requests")
     mesh = make_serving_mesh(1, 4)
     tp_adapter, _ = DistributedCachedDecoder.load(directory, mesh=mesh)
     tp, pool = serve_one_by_one(tp_adapter)
@@ -381,7 +378,15 @@ def tp_child(directory: str) -> int:
         raise SystemExit("TP arrays did not land on 4 devices")
     if packed.addressable_shards[0].data.shape == packed.shape:
         raise SystemExit("TP weights are replicated, not sharded")
-    compare_streams(tp, one, "tp(1,4) vs one-chip engine")
+    with jax.default_device(devs[0]):
+        base = compare_logits(engine_logits(one),
+                              reference_logits(one_adapter, one),
+                              "one-chip engine vs float32 reference")
+        tp_ref = reference_logits(one_adapter, tp)
+    compare_logits(engine_logits(tp), tp_ref,
+                   "tp(1,4) engine vs float32 reference",
+                   tol_rms=TP_VS_ONE_CHIP * base["rel_rms"],
+                   tol_max=TP_VS_ONE_CHIP * base["rel_max"])
     with open(os.path.join(directory, "one_chip.json"), "w") as f:
         json.dump({"prompts": [p.tolist() for p in prompts],
                    "tokens": [r.out_tokens for r in one],
@@ -403,24 +408,25 @@ def _http(port: int, method: str, path: str, body=None, timeout=5.0):
         c.close()
 
 
-def _generate_all(port: int, prompts: list) -> list:
+def _generate_all(port: int, prompts: list, deadline: float) -> list:
     """Token streams of ``prompts`` served one at a time by ``port``."""
     out = []
     for prompt in prompts:
         status, body = _http(port, "POST", "/v1/generate", {
-            "prompt": prompt, "max_new": GEN, "stream": False}, timeout=600)
+            "prompt": prompt, "max_new": GEN, "stream": False},
+            timeout=max(1.0, deadline - time.time()))
         if status != 200:
             raise SystemExit(f"port {port} answered {status}: {body}")
         out.append(body["tokens"])
     return out
 
 
-def fleet_step(directory: str) -> None:
+def fleet_step(directory: str, deadline: float) -> None:
     """Parent: serve ``--fleet 4`` from the artifact.  Every replica
     first answers the requests itself (which also compiles its programs,
     so the router's short upstream timeouts never see a compile); then
     the router answers them.  Every stream must equal the one-chip
-    engine's."""
+    engine's.  Gives up at ``deadline`` (``time.time()``)."""
     from concurrent.futures import ThreadPoolExecutor
 
     from repro.serve.fleet import free_port
@@ -437,9 +443,9 @@ def fleet_step(directory: str) -> None:
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.launch.serve", "--fleet", "4",
          "--router-port", str(port), "--load-quantized", directory,
-         "--tick-stall-s", "600", *ENGINE_FLAGS], env=env)
+         "--tick-stall-s", "600", *ENGINE_FLAGS], env=env,
+        start_new_session=True)
     try:
-        deadline = time.time() + 300
         while True:
             if proc.poll() is not None:
                 raise SystemExit(f"fleet exited {proc.returncode} at start")
@@ -450,19 +456,24 @@ def fleet_step(directory: str) -> None:
             except (OSError, ValueError):
                 pass
             if time.time() > deadline:
-                raise SystemExit("fleet replicas not healthy in 300s")
+                raise SystemExit("fleet replicas not healthy in time")
             time.sleep(1.0)
+        log("fleet: all 4 replicas healthy")
         t0 = time.perf_counter()
         with ThreadPoolExecutor(4) as ex:
             direct = list(ex.map(lambda r: _generate_all(
-                r["port"], ref["prompts"]), fz["replicas"]))
+                r["port"], ref["prompts"], deadline), fz["replicas"]))
         log(f"fleet: each replica answered {len(ref['prompts'])} requests "
             f"directly in {time.perf_counter() - t0:.1f}s (cold)")
-        routed = _generate_all(port, ref["prompts"])
+        routed = _generate_all(port, ref["prompts"], deadline)
         fz = _http(port, "GET", "/fleetz")[1]
     finally:
         proc.terminate()
-        rc = proc.wait(timeout=120)
+        try:
+            rc = proc.wait(timeout=60)
+        except subprocess.TimeoutExpired:  # the router and its replicas
+            os.killpg(proc.pid, signal.SIGKILL)
+            rc = proc.wait()
     same_direct = [sum(a == b for a, b in zip(d, ref["tokens"]))
                    for d in direct]
     same = sum(a == b for a, b in zip(routed, ref["tokens"]))
@@ -476,13 +487,15 @@ def fleet_step(directory: str) -> None:
 
 
 def four_chips() -> int:
+    deadline = time.time() + FOUR_CHIP_BUDGET_S
     tmp = tempfile.mkdtemp(prefix="quip-smoke4-")
     try:
         rc = subprocess.run([sys.executable, os.path.abspath(__file__),
-                             "--tp-child", tmp]).returncode
+                             "--tp-child", tmp],
+                            timeout=FOUR_CHIP_BUDGET_S).returncode
         if rc != 0:
             raise SystemExit(f"tp step failed ({rc})")
-        fleet_step(tmp)
+        fleet_step(tmp, deadline)
         with open(os.path.join(tmp, "one_chip.json")) as f:
             kind = json.load(f)["kind"]
     finally:

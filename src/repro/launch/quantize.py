@@ -10,15 +10,17 @@ This driver operates on smoke-scale dense models end-to-end on CPU (the
 per-layer math is size-agnostic; at cluster scale the same schedule runs
 layer-parallel over the model axis — DESIGN.md §3).
 
-Hessian accumulation is **streaming**: calibration segments pass through
-each block ``--calib-chunk`` segments at a time and feed
-``HessianAccumulator.update`` per segment, so per-block activation memory
+Hessian accumulation is **streaming**: each block's forward runs one
+calibration segment at a time (batched matmuls are not batch-size-
+invariant on every backend, jax 0.9.0's CPU included, so a batched
+forward would make H depend on the grouping), and ``--calib-chunk``
+segments' activations are held and folded through
+``HessianAccumulator.update`` together, so per-block activation memory
 is O(chunk · seg_len · d_ff) instead of O(batch · seg_len · d_ff).  The
-accumulator's fixed per-segment fold makes H bit-identical for every
-chunk size, including the one-shot path (``--calib-chunk 0``), as long
-as the backend's forward pass is batch-size-invariant — true for the CPU
-calibration path this driver runs on (tests/test_drivers.py pins it);
-on other backends the chunkings agree to reassociation error.
+accumulator's fixed per-segment fold then makes H bit-identical for
+every chunk size, including the one-shot path (``--calib-chunk 0``;
+tests/test_drivers.py pins it).  The price is B batch-1 forwards per
+block instead of B/chunk batched ones; not measured.
 
     PYTHONPATH=src python -m repro.launch.quantize --arch qwen3-14b --smoke \
         --bits 2 --method ldlq
@@ -176,9 +178,10 @@ def block_hessians(
     """Per-linear proxy Hessians for one block, streaming over segments.
 
     ``x`` (B, S, d) is the calibration activation entering the block;
-    activations at each linear's input are materialized only ``chunk``
-    segments at a time (``chunk <= 0``: the whole batch at once — the
-    one-shot path).  Each segment is folded through
+    the forward runs one segment at a time, and activations at each
+    linear's input are held only ``chunk`` segments at a time
+    (``chunk <= 0``: the whole batch at once — the one-shot path).  Each
+    segment is folded through
     ``HessianAccumulator.update`` individually, so the result is
     bit-identical for every chunk size.
     """
@@ -302,9 +305,10 @@ def main(argv=None):
     ap.add_argument("--calib-segments", type=int, default=16)
     ap.add_argument("--calib-len", type=int, default=128)
     ap.add_argument("--calib-chunk", type=int, default=8,
-                    help="calibration segments materialized at once per "
-                         "block (streaming Hessians; 0 = whole batch, the "
-                         "one-shot path — bit-identical either way)")
+                    help="calibration segments whose activations are held "
+                         "and folded into H together per block (the "
+                         "forward itself runs one segment at a time; 0 = "
+                         "whole batch — bit-identical either way)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     ap.add_argument("--out-dir", default=None,

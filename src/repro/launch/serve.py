@@ -556,14 +556,6 @@ def main(argv=None, *, on_done=None):
             ProcessReplicaFactory,
             Supervisor,
         )
-        from repro.serve.fleet.supervisor import local_tpu_chips
-
-        chips = local_tpu_chips()
-        if chips and args.fleet > chips:
-            raise SystemExit(
-                f"--fleet {args.fleet}: each replica needs a TPU chip of "
-                f"its own and this host has {chips}"
-            )
 
         first_spawn: dict[int, list] = {}
         for spec in args.replica_fault or ():
@@ -586,15 +578,18 @@ def main(argv=None, *, on_done=None):
             list(argv) if argv is not None else sys.argv[1:])
         factory = ProcessReplicaFactory(
             [sys.executable, "-m", "repro.launch.serve", *tail],
-            host=args.http_host, first_spawn_args=first_spawn, chips=chips,
+            host=args.http_host, first_spawn_args=first_spawn,
         )
-        sup = Supervisor(
-            factory, args.fleet, host=args.http_host,
-            probe_interval_s=args.probe_interval_s,
-            max_restarts=args.max_restarts,
-            backoff_base_s=args.restart_backoff_s,
-            replica_drain_timeout_s=args.drain_timeout_s + 30.0,
-        )
+        try:
+            sup = Supervisor(
+                factory, args.fleet, host=args.http_host,
+                probe_interval_s=args.probe_interval_s,
+                max_restarts=args.max_restarts,
+                backoff_base_s=args.restart_backoff_s,
+                replica_drain_timeout_s=args.drain_timeout_s + 30.0,
+            )
+        except ValueError as e:
+            raise SystemExit(f"--fleet: {e}")
         router = FleetRouter(
             sup, host=args.http_host, port=args.router_port,
             drain_timeout_s=args.drain_timeout_s,
@@ -639,7 +634,8 @@ def main(argv=None, *, on_done=None):
             )
         label = f"quip-{meta['quip_config']['bits']}bit[artifact]"
         print(f"[serve] loaded quantized artifact: {cfg.name} "
-              f"{meta['quip_config']['bits']}-bit ({args.load_quantized})")
+              f"{meta['quip_config']['bits']}-bit ({args.load_quantized}) "
+              f"on {jax.local_devices()}")
         if args.quality_baseline:
             from repro.serve.quality import check_artifact_quality, \
                 load_baseline

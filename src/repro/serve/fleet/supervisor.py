@@ -120,7 +120,9 @@ def local_tpu_chips() -> int:
 def chip_env(chip: int, port: int) -> dict:
     """libtpu variables that give one process exactly one chip of the
     host: a single-process 1x1x1 slice made of chip ``chip``, with its
-    own runtime port so several such processes run side by side."""
+    own runtime port so several such processes run side by side.  On a
+    four-chip v5e host (libtpu 0.0.34) four replicas started with these
+    each saw one TPU device and served at once."""
     return {
         "TPU_VISIBLE_CHIPS": str(chip),
         "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
@@ -142,28 +144,25 @@ class ProcessReplicaFactory:
     would kill every incarnation and trip the circuit breaker by
     design.
 
-    ``chips``: TPU chips on this host (:func:`local_tpu_chips`).  When
-    nonzero, replica ``i`` runs on chip ``i`` alone (:func:`chip_env`);
-    otherwise replicas inherit the parent's environment (CPU)."""
+    On a TPU host (``chips``, from :func:`local_tpu_chips`) replica
+    ``i`` runs on chip ``i`` alone (:func:`chip_env`), and the
+    :class:`Supervisor` refuses more replicas than chips; elsewhere
+    replicas inherit the parent's environment (CPU)."""
 
     def __init__(self, base_argv: list, *, host: str = "127.0.0.1",
                  first_spawn_args: Optional[dict] = None,
-                 echo: bool = True, chips: int = 0):
+                 echo: bool = True):
         self.base_argv = list(base_argv)
         self.host = host
         self.first_spawn_args = dict(first_spawn_args or {})
         self.echo = echo
-        self.chips = chips
+        self.chips = local_tpu_chips()
 
     def env(self, handle: ReplicaHandle) -> Optional[dict]:
         """The replica's environment: the parent's, pinned to its own
         chip on a TPU host (None = inherit unchanged)."""
         if not self.chips:
             return None
-        if handle.index >= self.chips:
-            raise ValueError(
-                f"replica {handle.index} has no chip of its own "
-                f"({self.chips} on this host)")
         return {**os.environ,
                 **chip_env(handle.index, free_port(self.host))}
 
@@ -283,6 +282,10 @@ class Supervisor:
             raise ValueError(f"fleet needs >= 1 replica, got {n}")
         if max_restarts < 0:
             raise ValueError(f"max_restarts must be >= 0, got {max_restarts}")
+        chips = getattr(factory, "chips", 0)
+        if chips and n > chips:
+            raise ValueError(f"fleet of {n}: each replica needs a TPU chip "
+                             f"of its own and this host has {chips}")
         self.factory = factory
         self.host = host
         self.probe_interval_s = probe_interval_s

@@ -505,13 +505,16 @@ def test_replicas_get_one_chip_each_on_a_tpu_host(monkeypatch):
     from repro.launch import serve
     from repro.serve.fleet import supervisor
 
-    f = ProcessReplicaFactory(["true"], chips=4)
+    monkeypatch.setattr(supervisor, "local_tpu_chips", lambda: 4)
+    f = ProcessReplicaFactory(["true"])
     envs = [f.env(ReplicaHandle(i, "127.0.0.1")) for i in range(4)]
     assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
     assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
     assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
-    with pytest.raises(ValueError, match="no chip"):
-        f.env(ReplicaHandle(4, "127.0.0.1"))
+    Supervisor(f, 4)
+    with pytest.raises(ValueError, match="this host has 4"):
+        Supervisor(f, 5)
+    monkeypatch.setattr(supervisor, "local_tpu_chips", lambda: 0)
     assert ProcessReplicaFactory(["true"]).env(ReplicaHandle(0, "h")) is None
     monkeypatch.setattr(supervisor, "local_tpu_chips", lambda: 2)
     with pytest.raises(SystemExit, match="this host has 2"):
