@@ -1,0 +1,12 @@
+"""The benchmark's own tests, run by path:
+
+    python -m pytest bench/tests
+
+They run on the CPU at tiny sizes; nothing here needs a chip."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, BENCH)
+sys.path.insert(0, os.path.join(os.path.dirname(BENCH), "src"))
