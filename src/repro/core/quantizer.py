@@ -94,13 +94,21 @@ class QuantizedLinear:
         off-TPU) regardless of how the layer was built.  ``matmul``, when
         given, replaces the packed matmul ``h -> h @ deq(Wq)^T`` (the
         tensor-parallel adapter runs it per shard).
+
+        The transforms run under the named scopes ``incoherence/rescale``,
+        ``incoherence/v`` and ``incoherence/u_t``, so a profiler trace
+        can tell their device time from the matmul's.
         """
         st = self.state
-        h = x if st.D is None else x / st.D
-        h = inc.apply_transform(st.V, h)
+        with jax.named_scope("incoherence"):
+            with jax.named_scope("rescale"):
+                h = x if st.D is None else x / st.D
+            with jax.named_scope("v"):
+                h = inc.apply_transform(st.V, h)
         z = self._matmul(h, use_kernel=use_kernel) if matmul is None \
             else matmul(h)
-        return inc.apply_transform(st.U, z, inverse=True)
+        with jax.named_scope("incoherence"), jax.named_scope("u_t"):
+            return inc.apply_transform(st.U, z, inverse=True)
 
     def _matmul(
         self, h: jax.Array, use_kernel: Optional[bool] = None

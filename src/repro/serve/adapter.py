@@ -111,33 +111,38 @@ def sample_tokens(logits, temps, top_ps, seeds, draws, greedy_only=False):
     knows every lane is temperature-0 (the common serving case and every
     ``--check``), the dispatch carries only the argmax — the sort/scan/
     PRNG sub-graph would otherwise dominate a smoke-scale verify tick.
+
+    Its ops run under the named scope ``sampler``.
     """
-    T, V = logits.shape[1], logits.shape[2]
-    greedy = jnp.argmax(logits, axis=-1)
-    if greedy_only:
-        return greedy.astype(jnp.int32)
+    with jax.named_scope("sampler"):
+        T, V = logits.shape[1], logits.shape[2]
+        greedy = jnp.argmax(logits, axis=-1)
+        if greedy_only:
+            return greedy.astype(jnp.int32)
 
-    def draw(lg, temp, top_p, seed, idx):
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), idx)
-        z = lg.astype(jnp.float32) / jnp.where(temp > 0.0, temp, 1.0)
-        p = jax.nn.softmax(z)
-        order = jnp.argsort(-p)
-        ps = p[order]
-        csum = jnp.cumsum(ps)
-        # nucleus filter; the head always survives (SamplingParams pins
-        # top_p > 0, but a degenerate caller must get argmax, not tail)
-        keep = (csum - ps < top_p).at[0].set(True)
-        ps = jnp.where(keep, ps, 0.0)
-        u = jax.random.uniform(key) * ps.sum()  # inverse-CDF, unnormalized
-        pick = jnp.searchsorted(jnp.cumsum(ps), u, side="right")
-        return order[jnp.clip(pick, 0, V - 1)]
+        def draw(lg, temp, top_p, seed, idx):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed), idx)
+            z = lg.astype(jnp.float32) / jnp.where(temp > 0.0, temp, 1.0)
+            p = jax.nn.softmax(z)
+            order = jnp.argsort(-p)
+            ps = p[order]
+            csum = jnp.cumsum(ps)
+            # nucleus filter; the head always survives (SamplingParams pins
+            # top_p > 0, but a degenerate caller must get argmax, not tail)
+            keep = (csum - ps < top_p).at[0].set(True)
+            ps = jnp.where(keep, ps, 0.0)
+            # inverse-CDF, unnormalized
+            u = jax.random.uniform(key) * ps.sum()
+            pick = jnp.searchsorted(jnp.cumsum(ps), u, side="right")
+            return order[jnp.clip(pick, 0, V - 1)]
 
-    sampled = jax.vmap(  # lanes x chunk positions
-        lambda lg, tp, pp, sd, d0: jax.vmap(
-            lambda l1, t: draw(l1, tp, pp, sd, d0 + t)
-        )(lg, jnp.arange(T, dtype=jnp.int32))
-    )(logits, temps, top_ps, seeds, draws)
-    return jnp.where(temps[:, None] > 0.0, sampled, greedy).astype(jnp.int32)
+        sampled = jax.vmap(  # lanes x chunk positions
+            lambda lg, tp, pp, sd, d0: jax.vmap(
+                lambda l1, t: draw(l1, tp, pp, sd, d0 + t)
+            )(lg, jnp.arange(T, dtype=jnp.int32))
+        )(logits, temps, top_ps, seeds, draws)
+        return jnp.where(temps[:, None] > 0.0, sampled,
+                         greedy).astype(jnp.int32)
 
 
 def _int8_roundtrip(x: jax.Array) -> jax.Array:

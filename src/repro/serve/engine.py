@@ -93,6 +93,7 @@ from repro.serve.telemetry import (
     NULL_TRACER,
     MetricsRegistry,
     Tracer,
+    compile_gauges,
     emit_metrics_line,
 )
 
@@ -116,7 +117,8 @@ _STAT_COUNTERS = (
     "cancelled",  # requests reaching CANCELLED
     "failed",  # requests reaching FAILED (any reason)
     "deadline_missed",  # FAILED specifically for blowing deadline_s
-    "quarantined_lanes",  # lanes the NaN/Inf screen pulled mid-batch
+    "decode_pages_live",  # pages real decode lanes hold (paged decode)
+    "decode_pages_attended",  # lanes x block-table width dispatched
     "admission_rejected",  # submits refused with AdmissionRejected
     # ---- quality canaries (serve/quality.py, DESIGN.md §13) ----
     "canary_runs",  # out-of-band teacher-forced NLL probes run
@@ -269,7 +271,9 @@ class Engine:
         for name, fn in self.pool.metrics_gauges().items():
             self.metrics.gauge(name, fn=fn)
         self.metrics.gauge("finished", fn=lambda: len(self.finished))
-        self.metrics.gauge("faults_injected", fn=lambda: len(self.faults.log))
+        # process-wide: compiles, compile_s, compile_cache_reads
+        for name, fn in compile_gauges().items():
+            self.metrics.gauge(name, fn=fn)
         # tick-stall watchdog: seconds since the last COMPLETED tick.  A
         # dispatch that wedges inside tick() stops this advancing, so a
         # supervisor (or any external LB reading /healthz) can tell a
@@ -822,7 +826,6 @@ class Engine:
         for b, r in enumerate(lanes):
             if ok[b] or r.state.terminal:
                 continue
-            self.metrics.inc("quarantined_lanes")
             self._fail(r, "nan_logits", now)
 
     def _fail_dispatch(self, lanes, exc: FaultInjected, now: float) -> None:
@@ -917,20 +920,23 @@ class Engine:
             covered = min(req.prefill_pos, len(req.prompt))
             self.pool.register_prefix(req.slot, req.prompt[:covered])
         if req.prefill_pos == len(req.prefix):
-            last = np.asarray(last_logits)
+            with self.tracer.span("prefill:wait"):
+                last = np.asarray(last_logits)
             if self.ecfg.screen_logits and not np.all(np.isfinite(last)):
                 # poisoned boundary logits: quarantine before emitting
-                self.metrics.inc("quarantined_lanes")
                 self._fail(req, "nan_logits", now)
                 return
             req.state = RequestState.DECODE
+            with self.tracer.span("prefill:boundary"):
+                tok = self._boundary_token(req, last)
+            held = self.now()  # the first token is on the host now
             req.emit(
-                self._boundary_token(req, last), now,
+                tok, held,
                 last if self.ecfg.record_logits or req.shadow else None,
             )
-            self._note_emit(req, now)
+            self._note_emit(req, held)
             if req.done:
-                self._finish(req, now)
+                self._finish(req, held)
 
     def _boundary_token(self, req: Request, logits: np.ndarray) -> int:
         """First-token selection at the prefill boundary.  With on-device
@@ -984,31 +990,34 @@ class Engine:
         group: lanes padded to a power of two (compile reuse across group
         sizes), chunk width fixed at ``prefill_chunk``, block tables
         bucketed to the longest prior context in the batch.  Padded lanes
-        and padded chunk tails scatter to the scratch page."""
-        C = self.ecfg.prefill_chunk
-        # lane bucketing shares page_bucket so the pow2 rounding has one
-        # source; group size is bounded by the token budget, never by it
-        B = page_bucket(len(chunks), 1 << 16)
-        tokens = np.zeros((B, C), np.int32)
-        positions = np.tile(np.arange(C, dtype=np.int32), (B, 1))
-        ctx_len = np.zeros((B,), np.int32)
-        slots: list[Optional[int]] = [None] * B
-        starts = [0] * B
-        ns = [0] * B
-        for b, (r, n) in enumerate(chunks):
-            start = r.prefill_pos
-            tokens[b, :n] = r.prefix[start : start + n]
-            positions[b] += start
-            ctx_len[b] = start
-            slots[b], starts[b], ns[b] = r.slot, start, n
-        pages, offs = self.pool.span_addresses(slots, starts, ns, C)
-        bt = self.pool.block_table(slots)
-        bt = bt[:, : self._active_pages(int(ctx_len.max(initial=1)))]
-        if self.faults.rules:
-            self.faults.lane_rids = tuple(r.rid for r, _ in chunks)
-            self.faults.poison_rids = tuple(
-                r.rid for r, n in chunks
-                if r.prefill_pos + n == len(r.prefix))
+        and padded chunk tails scatter to the scratch page.  Spans inside
+        ``prefill``: ``prefill:prepare`` up to the dispatch, then per
+        finished prompt ``prefill:wait`` and ``prefill:boundary``."""
+        with self.tracer.span("prefill:prepare"):
+            C = self.ecfg.prefill_chunk
+            # lane bucketing shares page_bucket so the pow2 rounding has
+            # one source; group size is bounded by the token budget
+            B = page_bucket(len(chunks), 1 << 16)
+            tokens = np.zeros((B, C), np.int32)
+            positions = np.tile(np.arange(C, dtype=np.int32), (B, 1))
+            ctx_len = np.zeros((B,), np.int32)
+            slots: list[Optional[int]] = [None] * B
+            starts = [0] * B
+            ns = [0] * B
+            for b, (r, n) in enumerate(chunks):
+                start = r.prefill_pos
+                tokens[b, :n] = r.prefix[start : start + n]
+                positions[b] += start
+                ctx_len[b] = start
+                slots[b], starts[b], ns[b] = r.slot, start, n
+            pages, offs = self.pool.span_addresses(slots, starts, ns, C)
+            bt = self.pool.block_table(slots)
+            bt = bt[:, : self._active_pages(int(ctx_len.max(initial=1)))]
+            if self.faults.rules:
+                self.faults.lane_rids = tuple(r.rid for r, _ in chunks)
+                self.faults.poison_rids = tuple(
+                    r.rid for r, n in chunks
+                    if r.prefill_pos + n == len(r.prefix))
         try:
             logits = self.adapter.prefill_paged(
                 tokens, positions, bt, ctx_len, pages, offs, self.pool
@@ -1049,38 +1058,56 @@ class Engine:
         return temps, top_ps, seeds, draws
 
     def _run_decode(self, decode: list[Request], now: float) -> None:
+        """One decode tick.  Spans inside ``decode``: ``decode:prepare``
+        (lane arrays, block table, addresses, sampling arrays), the
+        adapter's ``dispatch:*``, ``decode:wait`` (the host's read of the
+        selected tokens) and ``emit``."""
+        tr = self.tracer
         B = self.ecfg.n_slots
         assert len(decode) <= B
-        slots: list[Optional[int]] = [None] * B
-        tokens = np.zeros((B, 1), np.int32)
-        positions = np.zeros((B, 1), np.int32)
-        ctx_len = np.zeros((B,), np.int32)
-        for b, r in enumerate(decode):
-            slots[b] = r.slot
-            tokens[b, 0] = r.out_tokens[-1]
-            ctx_len[b] = self.pool.length(r.slot)
-            positions[b, 0] = ctx_len[b]
-        pos_list = [int(p) for p in positions[:, 0]]
         sel_np = None
-        if self.faults.rules:
-            self.faults.lane_rids = tuple(r.rid for r in decode)
-            self.faults.poison_rids = self.faults.lane_rids
         try:
+            with tr.span("decode:prepare"):
+                slots: list[Optional[int]] = [None] * B
+                tokens = np.zeros((B, 1), np.int32)
+                positions = np.zeros((B, 1), np.int32)
+                ctx_len = np.zeros((B,), np.int32)
+                for b, r in enumerate(decode):
+                    slots[b] = r.slot
+                    tokens[b, 0] = r.out_tokens[-1]
+                    ctx_len[b] = self.pool.length(r.slot)
+                    positions[b, 0] = ctx_len[b]
+                pos_list = [int(p) for p in positions[:, 0]]
+                if self.faults.rules:
+                    self.faults.lane_rids = tuple(r.rid for r in decode)
+                    self.faults.poison_rids = self.faults.lane_rids
+                if self.paged:
+                    bt = self.pool.block_table(slots)
+                    bt = bt[:, : self._active_pages(
+                        int(ctx_len.max(initial=1)))]
+                    pages, offs = self.pool.addresses(slots, pos_list)
+                    sampling = (self._sampling_arrays(decode, B)
+                                if self.ecfg.device_sample else None)
             if self.paged:
-                bt = self.pool.block_table(slots)
-                bt = bt[:, : self._active_pages(int(ctx_len.max(initial=1)))]
-                pages, offs = self.pool.addresses(slots, pos_list)
-                if self.ecfg.device_sample:
+                if sampling is not None:
                     sel, logits = self.adapter.decode_paged_sample(
                         tokens, positions, bt, ctx_len, pages, offs,
-                        self._sampling_arrays(decode, B), self.pool,
+                        sampling, self.pool,
                     )
-                    sel_np = np.asarray(sel[:, 0])
+                    with tr.span("decode:wait"):
+                        sel_np = np.asarray(sel[:, 0])
                 else:
                     logits = self.adapter.decode_paged(
                         tokens, positions, bt, ctx_len, pages, offs, self.pool
                     )
                 self.pool.note_written(slots, pos_list)
+                # padding waste of the paged kernel's grid: live pages
+                # against the (lanes x block-table width) it attends
+                ps = self.ecfg.page_size
+                live = ctx_len[:len(decode)]
+                self.metrics.inc("decode_pages_live", sum(
+                    pages_needed(int(c) + 1, ps) for c in live))
+                self.metrics.inc("decode_pages_attended", B * bt.shape[1])
             else:
                 ctx_k, ctx_v = self.pool.gather(slots)
                 logits, k_new, v_new = self.adapter(
@@ -1098,11 +1125,12 @@ class Engine:
             return
         if self.ecfg.screen_logits:
             self._screen_lanes(decode, logits, now)
-        with self.tracer.span("emit", lanes=len(decode)):
+        with tr.span("emit", lanes=len(decode)):
             logits_np = None
             if (sel_np is None or self.ecfg.record_logits
                     or any(r.shadow for r in decode)):
                 logits_np = np.asarray(logits[:, 0])
+            held = self.now()  # every lane's token is on the host now
             for b, r in enumerate(decode):
                 if r.state.terminal:
                     continue  # quarantined by the screen this tick
@@ -1111,14 +1139,14 @@ class Engine:
                     else self._select_token(r, logits_np[b])
                 )
                 r.emit(
-                    tok, now,
+                    tok, held,
                     logits_np[b] if self.ecfg.record_logits or r.shadow
                     else None,
                 )
-                self._note_emit(r, now)
+                self._note_emit(r, held)
                 self.metrics.inc("decode_tokens")
                 if r.done:
-                    self._finish(r, now)
+                    self._finish(r, held)
 
     def _run_decode_spec(self, decode: list[Request], now: float) -> None:
         """One speculative tick: draft up to K tokens per lane, verify
@@ -1202,6 +1230,7 @@ class Engine:
                     or any(r.shadow for r in decode)):
                 logits_np = np.asarray(logits)
             sel_np, n_acc_np = np.asarray(sel), np.asarray(n_acc)
+            held = self.now()  # every lane's tokens are on the host now
             extra = 0
             for b, r in enumerate(decode):
                 if r.state.terminal:
@@ -1212,10 +1241,10 @@ class Engine:
                 if self.ecfg.device_sample:
                     for i in range(int(n_acc_np[b]) + 1):
                         r.emit(
-                            int(sel_np[b, i]), now,
+                            int(sel_np[b, i]), held,
                             logits_np[b, i] if keep else None,
                         )
-                        self._note_emit(r, now)
+                        self._note_emit(r, held)
                         emitted += 1
                         if r.done:
                             break
@@ -1224,10 +1253,10 @@ class Engine:
                     while True:
                         tok = self._select_token(r, logits_np[b, i])
                         r.emit(
-                            tok, now,
+                            tok, held,
                             logits_np[b, i] if keep else None,
                         )
-                        self._note_emit(r, now)
+                        self._note_emit(r, held)
                         emitted += 1
                         if r.done or i >= n_drafts[b] or tok != drafts[b, i]:
                             break
@@ -1237,7 +1266,7 @@ class Engine:
                 self.metrics.inc("rolled_back_tokens", widths[b] - emitted)
                 extra += emitted - 1
                 if r.done:
-                    self._finish(r, now)  # releases the slot — no rollback
+                    self._finish(r, held)  # releases the slot — no rollback
                 else:
                     # un-write the rejected tail: the last emitted token's
                     # KV is computed NEXT tick (it is the new

@@ -1,6 +1,7 @@
 """Engine telemetry: span tracing, lifecycle metrics, Perfetto export.
 
-Three pieces, each off by default and costing nothing when off:
+Three pieces, each off by default and costing nothing when off, and a
+compile count:
 
   * :class:`Tracer` — a fixed-capacity ring buffer of *spans*.  Code
     brackets a phase with ``with tracer.span("prefill_batch", lanes=4):``
@@ -33,14 +34,22 @@ Three pieces, each off by default and costing nothing when off:
     :meth:`Tracer.export_chrome_trace` writes the JSON object Perfetto
     (https://ui.perfetto.dev) and ``chrome://tracing`` load directly.
     :func:`validate_chrome_trace` is the schema gate CI runs on every
-    emitted trace.
+    emitted trace.  ``otherData.profiler_clock_offset_ns`` puts the spans
+    on the profiler's host clock, so the export lines up with an xplane.
+
+  * :func:`compile_gauges` — this process's backend compiles, their
+    seconds and persistent compile-cache reads, from one
+    ``jax.monitoring`` listener; every engine exposes them as gauges.
 
 Span taxonomy (DESIGN.md §11): the engine's root span per tick is
 ``step``; its direct children are the phases ``schedule``, ``prefill``,
-``decode``, ``verify`` and ``emit``; adapter-level dispatch spans
-(``dispatch:prefill_paged``, ``dispatch:decode_paged``,
-``dispatch:verify_paged``) nest inside their phase.  TP adapters tag
-every span with the mesh geometry (``Tracer.tags``).
+``decode``, ``verify`` and ``emit``.  Inside ``decode``:
+``decode:prepare`` (host arrays), the adapter's ``dispatch:*``,
+``decode:wait`` (the host blocked on the selected tokens) and ``emit``;
+inside a paged ``prefill``: ``prefill:prepare``, ``dispatch:prefill_paged``
+and, per finished prompt, ``prefill:wait`` and ``prefill:boundary`` (the
+first token's draw).  TP adapters tag every span with the mesh geometry
+(``Tracer.tags``).
 :func:`phase_breakdown` aggregates a trace back into per-phase totals
 and a coverage ratio (phase time / step time) — the acceptance gate for
 "spans cover the tick".
@@ -50,9 +59,11 @@ from __future__ import annotations
 import dataclasses
 import json
 import sys
+import threading
 import time
 from typing import Callable, Optional
 
+import jax.monitoring
 import numpy as np
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
@@ -64,6 +75,7 @@ __all__ = [
     "NULL_TRACER",
     "Span",
     "Tracer",
+    "compile_gauges",
     "phase_breakdown",
     "validate_chrome_trace",
 ]
@@ -191,8 +203,13 @@ class Tracer:
         return _SpanHandle(self, name, attrs or None)
 
     def event(self, name: str, **attrs) -> None:
-        """Record an instant event (a lifecycle mark) at the current time."""
+        """Record an instant event (a lifecycle mark) at the current time;
+        ``annotate=True`` also marks it in the profiler trace, as a
+        zero-length annotation."""
         t = self.clock()
+        if self.annotate:
+            with _TraceAnnotation(name):
+                pass
         self._record(Span(name, t, t, self._depth, attrs or None, instant=True))
 
     def _record(self, span: Span) -> None:
@@ -270,6 +287,11 @@ class Tracer:
                 "tracer": "repro.serve.telemetry",
                 "sync": self.sync,
                 "dropped_spans": self.dropped,
+                # add to a span's t0 * 1e9 for the profiler's host time
+                # (Unix ns); an xplane's times count from its
+                # profile_start_time
+                "profiler_clock_offset_ns":
+                    time.time_ns() - round(self.clock() * 1e9),
                 **{str(k): str(v) for k, v in self.tags.items()},
             },
         }
@@ -555,3 +577,39 @@ def emit_metrics_line(snapshot: dict, *, t: Optional[float] = None,
                       keys: Optional[list] = None, file=None) -> None:
     print(format_metrics_line(snapshot, t=t, keys=keys),
           file=file or sys.stderr, flush=True)
+
+
+# ---------------------------------------------------------------------------
+# compiles
+# ---------------------------------------------------------------------------
+
+# one jax.monitoring listener per process, registered at import: the
+# backend compiles it reports (and their seconds), and the programs it
+# read from the persistent compile cache instead
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_hits"
+_compiles = {"compiles": 0, "compile_s": 0.0, "compile_cache_reads": 0}
+_compiles_lock = threading.Lock()  # programs compile on several threads
+
+
+def _on_duration(event: str, secs: float, **_) -> None:
+    if event == _COMPILE_EVENT:
+        with _compiles_lock:
+            _compiles["compiles"] += 1
+            _compiles["compile_s"] += secs
+
+
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_READ_EVENT:
+        with _compiles_lock:
+            _compiles["compile_cache_reads"] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
+
+
+def compile_gauges() -> dict:
+    """Gauge callbacks over this process's compiles since the module was
+    imported: ``compiles``, ``compile_s`` and ``compile_cache_reads``."""
+    return {k: (lambda k=k: _compiles[k]) for k in _compiles}

@@ -142,6 +142,7 @@ def test_fault_blast_radius_is_one_request(fp_ctx, baseline, path, kind):
         assert list(r.out_tokens) == baseline[i], f"survivor {i} diverged"
     assert len(plan.log) == 1 and plan.log[0]["kind"] == kind
     assert eng.metrics.snapshot()[f"fault:{kind}"] == 1
+    assert eng.metrics.snapshot()[f"finish:{victim.finish_reason}"] == 1
     _assert_pool_clean(eng)
 
 

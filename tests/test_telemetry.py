@@ -413,3 +413,200 @@ def test_engine_metrics_every_emits_snapshots(fp_model, capfd):
     engine.run(metrics_every=1e-6)
     err = capfd.readouterr().err
     assert "[metrics t=" in err and "steps=" in err
+
+
+# ---------------------------------------------------------------------------
+# spans and counters inside the tick, compile gauges, token stamps
+# ---------------------------------------------------------------------------
+
+
+def _children(spans, parent):
+    """Names of the spans one level below ``parent`` inside its interval,
+    in start order (instant marks excluded)."""
+    return [s.name for s in sorted(spans, key=lambda s: s.t0)
+            if not s.instant and s.depth == parent.depth + 1
+            and parent.t0 <= s.t0 and s.t1 <= parent.t1]
+
+
+def test_tick_spans_split_prepare_dispatch_wait(fp_model):
+    cfg, model, params = fp_model
+    prompts = make_calibration(cfg.vocab, n_segments=3, seg_len=10,
+                               seed=9).tokens
+    tr = Tracer()
+    engine, reqs = _run(
+        CachedDecoder.from_model(model, params), prompts, 5, tracer=tr,
+        paged_decode=True, paged_prefill=True, device_sample=True,
+    )
+    spans = tr.spans
+    steps = [s for s in spans if s.name == "step"]
+    assert len(steps) == engine.stats["steps"]
+    n_decode = n_prefill = 0
+    for step in steps:
+        phases = _children(spans, step)
+        assert phases[0] == "schedule"
+        assert set(phases) <= {"schedule", "prefill", "decode"}
+        for ph in (s for s in spans if s.name in ("prefill", "decode")
+                   and step.t0 <= s.t0 and s.t1 <= step.t1):
+            inner = _children(spans, ph)
+            if ph.name == "decode":
+                n_decode += 1
+                assert inner == ["decode:prepare",
+                                 "dispatch:decode_paged_sample",
+                                 "decode:wait", "emit"]
+            else:
+                n_prefill += 1
+                assert inner[:2] == ["prefill:prepare",
+                                     "dispatch:prefill_paged"]
+                # each prompt finished this tick: its logits, its token
+                assert inner[2:] == ["prefill:wait",
+                                     "prefill:boundary"] * (
+                    (len(inner) - 2) // 2)
+    assert n_decode and n_prefill
+    # one first token, so one boundary, per request
+    assert sum(s.name == "prefill:boundary" for s in spans) == len(reqs)
+    # the new spans sit below the phases: the breakdown is unchanged
+    pb = phase_breakdown(spans)
+    assert set(pb["phases"]) == {"schedule", "prefill", "decode"}
+    assert pb["coverage"] >= 0.95
+
+
+def test_decode_page_counters_by_hand(fp_model):
+    """Two requests of 5 and 9 prompt tokens on 4-token pages: the first
+    tick prefills both, then each decode tick dispatches 4 lanes against
+    a block table 4 pages wide (3 pages of the 9-token context, bucketed
+    up to a power of two), while the lanes hold 2 + 3 live pages with
+    their new tokens."""
+    cfg, model, params = fp_model
+    engine = Engine(CachedDecoder.from_model(model, params), EngineConfig(
+        max_seq_len=16, n_slots=4, page_size=4, token_budget=32,
+        prefill_chunk=16, paged_decode=True, paged_prefill=True))
+    rng = np.random.default_rng(0)
+    for n in (5, 9):
+        engine.submit(rng.integers(1, cfg.vocab, n), max_new=4)
+    engine.tick()  # both prompts prefill
+    assert engine.stats["decode_pages_attended"] == 0
+    engine.tick()  # contexts 5, 9: pages of 6 and 10 tokens
+    assert engine.stats["decode_pages_live"] == 2 + 3
+    assert engine.stats["decode_pages_attended"] == 4 * 4
+    engine.tick()  # contexts 6, 10: pages of 7 and 11 tokens
+    assert engine.stats["decode_pages_live"] == (2 + 3) * 2
+    assert engine.stats["decode_pages_attended"] == 4 * 4 * 2
+
+
+def test_compile_gauges_count_new_shapes_only(fp_model):
+    cfg, model, params = fp_model
+    engine = Engine(CachedDecoder.from_model(model, params), EngineConfig(
+        max_seq_len=16, n_slots=2, page_size=4, paged_decode=True))
+    keys = ("compiles", "compile_s", "compile_cache_reads")
+    assert all(k in engine.summary() for k in keys)
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x7, x9 = np.ones(7, np.float32), np.ones(9, np.float32)
+    c0 = engine.summary()["compiles"]
+    f(x7).block_until_ready()
+    c1 = engine.summary()["compiles"]
+    f(x7).block_until_ready()  # the same shape: no compile
+    c2 = engine.summary()["compiles"]
+    f(x9).block_until_ready()  # a new shape: one
+    c3 = engine.summary()["compiles"]
+    assert (c1 - c0, c2 - c1, c3 - c2) == (1, 0, 1)
+    assert engine.summary()["compile_s"] > 0
+    # process-wide: a second engine reads the same count
+    other = Engine(CachedDecoder.from_model(model, params), EngineConfig(
+        max_seq_len=16, n_slots=2, page_size=4, paged_decode=True))
+    assert other.summary()["compiles"] == engine.summary()["compiles"]
+
+
+def test_null_tracer_records_nothing_through_a_run(fp_model):
+    cfg, model, params = fp_model
+    prompts = make_calibration(cfg.vocab, n_segments=2, seg_len=8,
+                               seed=10).tokens
+    engine, _ = _run(CachedDecoder.from_model(model, params), prompts, 3,
+                     paged_decode=True, paged_prefill=True,
+                     device_sample=True)
+    assert engine.tracer is NULL_TRACER
+    assert len(NULL_TRACER) == 0 and NULL_TRACER.spans == []
+
+
+def test_programs_carry_the_incoherence_and_sampler_scopes(
+        quantized_smoke):
+    cfg, qm = quantized_smoke
+    ad = CachedDecoder.from_quantized(qm)
+    pool = ad.make_pool(n_pages=8, page_size=4, n_slots=2,
+                        max_pages_per_seq=4, dtype=None, prefix_cache=False)
+    z = lambda *s: np.zeros(s, np.int32)
+    decode = ad._fwd_paged_s.lower(
+        ad.weights, z(2, 1), z(2, 1), z(2, 4), z(2), z(2), z(2),
+        np.full(2, 0.7, np.float32), np.ones(2, np.float32), z(2), z(2),
+        pool.arrays, False).as_text(debug_info=True)
+    prefill = ad._fwd_prefill.lower(
+        ad.weights, z(2, 8), z(2, 8), z(2, 4), z(2), z(2, 8), z(2, 8),
+        pool.arrays).as_text(debug_info=True)
+    for text in (decode, prefill):
+        assert "incoherence/v/" in text and "incoherence/u_t/" in text
+    assert "/sampler/" in decode and "/sampler/" not in prefill
+
+
+def test_token_stamps_are_when_the_host_holds_the_token(fp_model):
+    """Each token is stamped once the host has read it: a decode token
+    within 1 ms of the moment the tick returns it (what the benchmark
+    harness stamps), a first token (read at its prefill boundary, before
+    the tick's decode) no later than that and after the tick began."""
+    cfg, model, params = fp_model
+    prompts = make_calibration(cfg.vocab, n_segments=3, seg_len=10,
+                               seed=11).tokens
+    engine = Engine(CachedDecoder.from_model(model, params), EngineConfig(
+        max_seq_len=prompts.shape[1] + 6, n_slots=4, page_size=4,
+        token_budget=32, prefill_chunk=8, paged_decode=True,
+        paged_prefill=True, device_sample=True))
+    reqs = [engine.submit(np.asarray(p), max_new=6) for p in prompts]
+    returned = {r.rid: [] for r in reqs}
+    starts = {r.rid: [] for r in reqs}
+    while not engine.idle:
+        t0 = engine.now()
+        res = engine.tick()
+        t = engine.now()
+        for req, _ in res.emitted:
+            returned[req.rid].append(t)
+            starts[req.rid].append(t0)
+    for r in reqs:
+        eng, drv = np.asarray(r.token_times), np.asarray(returned[r.rid])
+        assert len(eng) == len(drv) == 6
+        assert np.all(eng <= drv) and np.all(eng >= starts[r.rid])
+        np.testing.assert_array_less(drv[1:] - eng[1:], 1e-3)
+        assert r.t_first == eng[0]
+
+
+def test_annotated_span_lines_up_with_the_profiler_trace(tmp_path):
+    """One clock: a span recorded with ``annotate=True`` and its event in
+    the profiler's trace agree, after the exported offset, on start and
+    on duration within 1 ms; instant marks enter the trace too."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer(annotate=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        time.sleep(0.01)
+        with tr.span("decode:wait"):
+            time.sleep(0.02)
+        tr.event("first_token", rid=0)
+    finally:
+        jax.profiler.stop_trace()
+    offset = tr.export_chrome_trace(tmp_path / "t.json")[
+        "otherData"]["profiler_clock_offset_ns"]
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    start = events = None
+    for plane in pd.planes:
+        if plane.name == "Task Environment":
+            start = dict(plane.stats)["profile_start_time"]
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("decode:wait", "first_token"):
+                    events = (events or {}) | {e.name: e}
+    span = next(s for s in tr.spans if s.name == "decode:wait")
+    ev = events["decode:wait"]
+    assert abs(start + ev.start_ns - (span.t0 * 1e9 + offset)) < 1e6
+    assert abs(ev.duration_ns - span.dur * 1e9) < 1e6
+    assert "first_token" in events
