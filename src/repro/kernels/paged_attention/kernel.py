@@ -1,54 +1,64 @@
 """Pallas TPU kernels: GQA attention directly against the paged KV pool.
 
-Two entry points share one grid shape and one online-softmax core:
+Two entry points, each with its own grid:
 
-* :func:`paged_attention_kernel` — decode: one query token per lane;
+* :func:`paged_attention_kernel` — decode: one query token per lane.  Grid
+  ``(lane, page_block)``: one step covers a block of ``ppb`` pages of one
+  lane for all KV heads, fetched by hand;
 * :func:`paged_prefill_kernel` — chunked prefill: a ``(B, C)`` token chunk
-  batch per dispatch.  The grid grows one trailing "arbitrary" step that
-  folds the chunk's own K/V in with an intra-chunk causal mask, so a
+  batch per dispatch.  Grid ``(lane, kv_head, page + 1)``: one page of one
+  head per step through BlockSpecs, and one trailing "arbitrary" step
+  that folds the chunk's own K/V in with an intra-chunk causal mask, so a
   whole cross-request prefill chunk batch attends its paged prior context
-  plus itself in a single pass (DESIGN.md §9).
+  plus itself in a single pass (DESIGN.md §9).  Its ``G·C``-row q tile
+  wants per-head blocks; decode's few rows per head want the opposite.
 
-The serving engine used to materialize a dense ``(L, B, Pmax*ps, KV, hd)``
-copy of every context page per decode step (``PagedKVPool.gather``) — an
-O(allocated-pages) HBM copy per emitted token that un-does the bandwidth win
-2-bit weights buy (DESIGN.md §7).  This kernel reads the **physical page
-pool in place**:
+Both read the **physical page pool in place** rather than a dense
+``(L, B, Pmax*ps, KV, hd)`` gather of every context page (DESIGN.md §7).
+The pool is head-major, ``(L, P, KV, ps, hd)``: one page of one head is a
+whole trailing ``(ps, hd)`` tile, and one page of all heads a contiguous
+``(KV, ps, hd)`` slab.  Per-lane **block tables** and **context lengths**
+ride in scalar prefetch (SMEM).  Neither kernel slices the pool in XLA:
+prefill bakes the layer index into its index maps, and decode takes it
+in scalar prefetch too, so one program's layer calls share one kernel.
 
-* the pool is head-major, ``(L, P, KV, ps, hd)``, so one grid step's
-  ``(ps, hd)`` page slice of one kv head is a whole trailing tile: the
-  TPU lowering requires the last two block dims to be multiples of
-  (8, 128) or the full array dims, which a ``(ps, 1, hd)`` block of a
-  token-major ``(ps, KV, hd)`` page breaks for any ``KV > 1``;
-* the grid is ``(lane, kv_head, page)`` with the page dimension innermost
-  ("arbitrary"), so the fp32 output tile is revisited as an online-softmax
-  accumulator (running max ``m``, normalizer ``l``, unnormalized ``o``);
-* per-lane **block tables** and **context lengths** ride in scalar-prefetch
-  (SMEM) — the k/v BlockSpec index maps dereference ``bt[lane, page]`` to
-  DMA exactly one physical page ``(ps, hd)`` slice per kv head per step.
-  The page index is **clamped to the lane's last valid page**: block tables
-  are bucketed to the longest live context in the batch, so a short lane
-  would otherwise stream its trailing scratch/dead pages from HBM just to
-  mask them — with the clamp, every grid step past the lane's end re-asks
-  for the page already resident in VMEM and Mosaic skips the DMA (the
-  revisited-block convention).  Positions past ``ctx_len`` are still masked
-  out of the softmax, so clamped steps contribute exactly zero.  Dead
-  block-table entries past a lane's last valid page are therefore never
-  dereferenced — EXCEPT ``bt[lane, 0]``: an empty lane (``ctx_len == 0``)
-  has no valid page to clamp to, so its steps all read entry 0, which
-  must hold a real page id (the engine zero-fills block tables, and
-  physical page 0 is the reserved scratch page);
-* the layer index is baked into the index map, so the kernel addresses the
-  full ``(L, P, KV, ps, hd)`` pool tensor without an XLA slice copy;
-* int8 pages carry per-(head, token) fp32 scales (``(L, P, KV, ps)``); a
-  step reads the page's whole ``(KV, ps)`` scale tile, picks its head's
-  row, and applies it to the score and probability rows (a row scale on
-  ``q·kᵀ`` columns is a scale on k's rows) — KV reads stay 1 byte/elem.
+Decode:
+
+* the pool stays in HBM; for each page of a block the kernel copies the
+  slab ``pool.at[layer, bt[lane, i]]`` into a ``(2, ppb, KV, ps, hd)``
+  VMEM double buffer, and starts block ``j+1``'s copies before it
+  computes block ``j``;
+* ``ppb`` is the largest power of two ≤ 16 that divides the block-table
+  width (:func:`_pages_per_block`);
+* scores are one head-batched product over the block's ``ppb·ps``
+  positions, and the fp32 ``(KV, G, ·)`` output blocks are revisited
+  across blocks as an online-softmax accumulator (running max ``m``,
+  normalizer ``l``, unnormalized ``o``);
+* **dead blocks do nothing**: a block at or past ``ctx_len`` starts no DMA
+  and no compute, so it costs a bare grid step.  Inside a lane's last
+  block, pages past ``ctx_len`` are not copied and their block-table
+  entries are never read — nor is entry 0 of an empty lane, which returns
+  ``m = NEG``, ``l = 0``, ``o = 0``.  Positions past ``ctx_len`` are masked
+  and their value rows zeroed, so stale buffer slots (NaN included)
+  contribute exactly zero;
+* int8 pages carry per-(head, token) fp32 scales (``(L, P, KV, ps)``).
+  Mosaic refuses a hand DMA of a page's ``(KV, ps)`` scale tile (an fp32
+  minor dim narrower than 128 lanes), so the wrapper gathers the attended
+  pages' scales and they ride a pipelined BlockSpec, one block per step;
+  K and V are dequantized in fp32 before the products.
 
 The new token's own K/V never touches the pool here: the wrapper (ops.py)
 folds the self-attention term into the accumulator analytically and
 normalizes, so decode needs no concat and no pre-scatter.  Outputs are the
 *unnormalized* accumulator plus ``(m, l)`` statistics for that merge.
+
+Prefill's BlockSpec index maps clamp the page index to the lane's last
+valid page (:func:`_pool_specs`), so steps past a lane's context re-ask
+for the resident page and Mosaic skips the DMA; an empty lane's steps all
+read entry 0, which must hold a real page id (the engine zero-fills block
+tables, and physical page 0 is the reserved scratch page).  int8 prefill
+steps read the page's whole ``(KV, ps)`` scale tile and pick their head's
+row.
 """
 from __future__ import annotations
 
@@ -103,41 +113,114 @@ def _page_scores(q, k_ref, v_ref, ks_ref, vs_ref, h, int8_pages):
     return s * _head_row(ks_ref, h), v, _head_row(vs_ref, h)
 
 
-def _pa_kernel(
+_MAX_PAGES_PER_BLOCK = 16
+
+
+def _pages_per_block(pa: int) -> int:
+    """Pages one decode grid step covers: the largest power of two no
+    larger than ``_MAX_PAGES_PER_BLOCK`` that divides the block-table
+    width ``pa`` (16 for every bucket from 16 up, and for a 320 cap)."""
+    ppb = _MAX_PAGES_PER_BLOCK
+    while pa % ppb:
+        ppb //= 2
+    return ppb
+
+
+def _decode_kernel(
+    layer_ref,  # (1,) int32 scalar-prefetch layer index
     bt_ref,  # (B, Pa) int32 scalar-prefetch block table
     cl_ref,  # (B,)    int32 scalar-prefetch context lengths
-    q_ref,  # (1, 1, G, hd)
-    k_ref,  # (1, 1, 1, ps, hd)
-    v_ref,  # (1, 1, 1, ps, hd)
-    *refs,  # [ks_ref (1,1,KV,ps), vs_ref (1,1,KV,ps)], o_ref, m_ref, l_ref
-    page_size: int,
+    q_ref,  # (1, KV, G, hd)
+    k_hbm,  # (L, P, KV, ps, hd) whole pool, left in HBM
+    v_hbm,
+    *refs,  # [ks_ref, vs_ref (1, ppb, KV, ps)], o_ref, m_ref, l_ref,
+    #         k_buf, v_buf (2, ppb, KV, ps, hd), k_sem, v_sem (2,)
     int8_pages: bool,
 ):
+    layer = layer_ref[0]
     if int8_pages:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref = refs
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref = refs
-    b, h, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        ks_ref, vs_ref, *refs = refs
+    o_ref, m_ref, l_ref, k_buf, v_buf, k_sem, v_sem = refs
+    copies = ((k_hbm, k_buf, k_sem), (v_hbm, v_buf, v_sem))
+    b, j = pl.program_id(0), pl.program_id(1)
+    _, ppb, KV, ps, hd = k_buf.shape
+    T = ppb * ps  # token positions per block
+    ctx = cl_ref[b]
 
-    @pl.when(p == 0)
+    def dma(blk, slot, start: bool):
+        """Start (or wait for) the copies of block ``blk``'s live pages
+        into buffer ``slot``.  A page at or past ``ctx`` is skipped, and
+        its block-table entry is never read."""
+        def one_page(i, carry):
+            p = blk * ppb + i
+
+            @pl.when(p * ps < ctx)
+            def _page():
+                page = bt_ref[b, p]
+                for src, dst, sem in copies:
+                    cp = pltpu.make_async_copy(
+                        src.at[layer, page], dst.at[slot, i], sem.at[slot]
+                    )
+                    if start:
+                        cp.start()
+                    else:
+                        cp.wait()
+
+            return carry
+
+        jax.lax.fori_loop(0, ppb, one_page, 0)
+
+    @pl.when(j == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # (G, hd)
-    s, v, v_row = _page_scores(q, k_ref, v_ref, ks_ref, vs_ref, h, int8_pages)
+    @pl.when(j * T < ctx)
+    def _live_block():
+        slot = j % 2
 
-    # positions covered by this physical page; everything at or past the
-    # lane's ctx_len (incl. whole pages resolved to the scratch page) is
-    # masked.
-    pos = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1
-    )
-    valid = pos < cl_ref[b]  # (1, ps), broadcasts over G
-    s = jnp.where(valid, s, _NEG)
-    _online_update(s, valid, v, o_ref, m_ref, l_ref, v_row)
+        @pl.when(j == 0)
+        def _first():
+            dma(0, 0, start=True)
+
+        @pl.when(
+            jnp.logical_and(j + 1 < pl.num_programs(1), (j + 1) * T < ctx)
+        )
+        def _prefetch_next():
+            dma(j + 1, 1 - slot, start=True)
+
+        dma(j, slot, start=False)
+
+        q = q_ref[0].astype(jnp.float32)  # (KV, G, hd)
+        k = k_buf[slot].astype(jnp.float32)  # (ppb, KV, ps, hd)
+        v = v_buf[slot].astype(jnp.float32)
+        if int8_pages:  # a per-(page, head, token) row scale
+            k = k * ks_ref[0][..., None]
+            v = v * vs_ref[0][..., None]
+        k = jnp.swapaxes(k, 0, 1).reshape(KV, T, hd)
+        v = jnp.swapaxes(v, 0, 1).reshape(KV, T, hd)
+        # slots of pages past ctx hold whatever an earlier block left
+        # there: mask their scores and zero their values (0 * NaN is NaN)
+        iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32)
+        valid = j * T + iota((1, 1, T), 2) < ctx  # key columns
+        rows = j * T + iota((1, T, 1), 1) < ctx  # value rows
+        v = jnp.where(rows, v, 0.0)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * (hd**-0.5)  # (KV, G, T)
+        s = jnp.where(valid, s, _NEG)
+        m_prev = m_ref[0]  # (KV, G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pmat = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[0] = alpha * l_ref[0] + jnp.sum(pmat, -1, keepdims=True)
+        o_ref[0] = o_ref[0] * alpha + jax.lax.dot_general(
+            pmat, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[0] = m_new
 
 
 def _check_operands(q, k_pages, v_pages, block_tables, ctx_len, layer,
@@ -200,7 +283,8 @@ def paged_attention_kernel(
     k/v_pages    (L, P, KV, ps, hd) physical pool (fp, or int8 + scales);
     block_tables (B, Pa) int32 physical page per logical page (Pa is the
                  *attended* prefix of the lane's allocation, bucketed by the
-                 caller — step cost scales with context, not allocation);
+                 caller; blocks past a lane's context cost a bare grid step,
+                 entries past its last page are never read);
     ctx_len      (B,) int32 valid context tokens per lane.
 
     Returns ``(o, m, l)``: unnormalized accumulator (B, KV, G, hd) and the
@@ -210,33 +294,67 @@ def paged_attention_kernel(
     int8_pages = _check_operands(
         q, k_pages, v_pages, block_tables, ctx_len, layer, k_scale, v_scale
     )
+    if int8_pages:
+        # Mosaic refuses a hand DMA of a page's (KV, ps) fp32 scale tile
+        # (its minor dim is narrower than one 128-lane tile), so the
+        # attended pages' scales are gathered here, with entries past a
+        # lane's last page read as the scratch page 0, and ride the
+        # pipelined BlockSpec path one block at a time.
+        Pa, ps = block_tables.shape[1], k_pages.shape[3]
+        live = jnp.arange(Pa)[None, :] * ps < ctx_len[:, None]
+        bt_live = jnp.where(live, block_tables, 0)
+        k_scale, v_scale = k_scale[layer][bt_live], v_scale[layer][bt_live]
+    # the layer index rides in scalar prefetch, so every layer's call of
+    # one program shares one traced and lowered kernel: lowering a kernel
+    # per layer cost about 45 s of set-up per qwen3-14b decode program
+    return _decode_call(
+        jnp.full((1,), layer, jnp.int32), q, k_pages, v_pages, block_tables,
+        ctx_len, k_scale, v_scale, interpret=interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _decode_call(layer, q, k_pages, v_pages, block_tables, ctx_len,
+                 k_scale, v_scale, *, interpret):
+    """The decode ``pallas_call``; ``k/v_scale`` are None or the gathered
+    (B, Pa, KV, ps) scales of the attended pages."""
     B, KV, G, hd = q.shape
     Pa = block_tables.shape[1]
-    kv_spec, sc_spec = _pool_specs(k_pages.shape, layer)
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda b, h, p, bt, cl: (b, h, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
+    ppb = _pages_per_block(Pa)
+    ps = k_pages.shape[3]
+    lane = lambda b, j, lyr, bt, cl: (b, 0, 0, 0)
+    pool = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [pl.BlockSpec((1, KV, G, hd), lane), pool, pool]
     operands = [q, k_pages, v_pages]
+    int8_pages = k_scale is not None
     if int8_pages:
+        # a dead block re-asks for the lane's last live block: no fetch
+        T = ppb * ps
+        sc_spec = pl.BlockSpec(
+            (1, ppb, KV, ps),
+            lambda b, j, lyr, bt, cl: (
+                b, jnp.minimum(j, jnp.maximum(pl.cdiv(cl[b], T) - 1, 0)),
+                0, 0,
+            ),
+        )
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale, v_scale]
+    scratch = [pltpu.VMEM((2, ppb, KV, ps, hd), k_pages.dtype)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((2,))] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, Pa),
+        num_scalar_prefetch=3,
+        grid=(B, Pa // ppb),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, p, bt, cl: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G, 1), lambda b, h, p, bt, cl: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G, 1), lambda b, h, p, bt, cl: (b, h, 0, 0)),
+            pl.BlockSpec((1, KV, G, hd), lane),
+            pl.BlockSpec((1, KV, G, 1), lane),
+            pl.BlockSpec((1, KV, G, 1), lane),
         ],
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
-        functools.partial(
-            _pa_kernel, page_size=k_pages.shape[3], int8_pages=int8_pages
-        ),
+        functools.partial(_decode_kernel, int8_pages=int8_pages),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, KV, G, hd), jnp.float32),
@@ -244,11 +362,11 @@ def paged_attention_kernel(
             jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_tables, ctx_len, *operands)
+    )(layer, block_tables, ctx_len, *operands)
 
 
 def _pool_specs(pool_shape, layer):
@@ -312,9 +430,8 @@ def _prefill_kernel(
 
     @pl.when(p < n_ctx)
     def _ctx_page():
-        # identical to the decode page step: every chunk token attends all
-        # prior-context positions < cl, so the whole (G*C)-row tile shares
-        # one page mask.
+        # every chunk token attends all prior-context positions < cl, so
+        # the whole (G*C)-row tile shares one page mask.
         s, v, v_row = _page_scores(
             q, k_ref, v_ref, ks_ref, vs_ref, h, int8_pages
         )  # (G*C, ps)
@@ -442,8 +559,8 @@ def paged_prefill_kernel(
                  here while the chunk arrays carry the int8 round-trip).
 
     Grid is ``(lane, kv_head, page+1)``: the context pages stream through
-    the decode kernel's online-softmax step (index-map clamp included), and
-    the one extra trailing step folds in the intra-chunk causal block and
+    a per-page online-softmax step (index-map clamp included), and the one
+    extra trailing step folds in the intra-chunk causal block and
     normalizes.  Returns the normalized output (B, KV, G, C, hd) fp32.
     """
     int8_pages = _check_prefill_operands(
@@ -454,8 +571,8 @@ def paged_prefill_kernel(
     B, KV, G, C, hd = q.shape
     Pa = block_tables.shape[1]
     qf = q.reshape(B, KV, G * C, hd)
-    # the same clamp as decode: steps at/past a lane's last valid page
-    # (including the whole trailing chunk step) re-ask for the resident page
+    # steps at/past a lane's last valid page (including the whole trailing
+    # chunk step) re-ask for the resident page
     kv_spec, sc_spec = _pool_specs(k_pages.shape, layer)
     # chunk K/V go head-major too, so each step reads one (C, hd) tile
     heads_major = lambda x: x.transpose(0, 2, 1, 3)  # (B, KV, C, hd)

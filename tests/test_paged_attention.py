@@ -21,18 +21,20 @@ from repro.kernels.paged_attention import (
 
 
 def _setup(
-    *, L=2, P=9, ps=4, KV=2, G=2, hd=16, B=3, Pa=3, int8=False, seed=0
+    *, L=2, P=9, ps=4, KV=2, G=2, hd=16, B=3, Pa=3, int8=False, seed=0,
+    dtype=jnp.float32,
 ):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     H = KV * G
+    int8 = int8 or dtype == jnp.int8
     if int8:
         kp = jax.random.randint(ks[0], (L, P, KV, ps, hd), -127, 128, jnp.int8)
         vp = jax.random.randint(ks[1], (L, P, KV, ps, hd), -127, 128, jnp.int8)
         k_sc = jnp.abs(jax.random.normal(ks[4], (L, P, KV, ps))) * 0.02 + 1e-3
         v_sc = jnp.abs(jax.random.normal(ks[5], (L, P, KV, ps))) * 0.02 + 1e-3
     else:
-        kp = jax.random.normal(ks[0], (L, P, KV, ps, hd), jnp.float32)
-        vp = jax.random.normal(ks[1], (L, P, KV, ps, hd), jnp.float32)
+        kp = jax.random.normal(ks[0], (L, P, KV, ps, hd)).astype(dtype)
+        vp = jax.random.normal(ks[1], (L, P, KV, ps, hd)).astype(dtype)
         k_sc = v_sc = None
     q = jax.random.normal(ks[2], (B, H, hd), jnp.float32)
     kn = jax.random.normal(ks[3], (B, KV, hd), jnp.float32) * 0.5
@@ -59,12 +61,40 @@ def _both(q, kn, vn, kp, vp, bt, cl, layer, k_sc=None, v_sc=None):
     )
 
 
-@pytest.mark.parametrize("G", [1, 2, 4])
-def test_kernel_matches_oracle_gqa_groups(G):
-    q, kn, vn, kp, vp, bt, *_ = _setup(G=G, seed=G)
+# (G, KV): the first three keep the ids they had before the KV axis;
+# qwen3-14b groups 5 query heads on each of 8 KV heads, and a two-way
+# tensor-parallel shard holds 2 of them
+GQA = [pytest.param(1, 2, id="1"), pytest.param(2, 2, id="2"),
+       pytest.param(4, 2, id="4")] + [
+    pytest.param(G, KV, id=f"G{G}-KV{KV}")
+    for G in (1, 2, 4, 5) for KV in (1, 2, 8) if KV != 2 or G == 5
+]
+
+
+@pytest.mark.parametrize("G,KV", GQA)
+def test_kernel_matches_oracle_gqa_groups(G, KV):
+    q, kn, vn, kp, vp, bt, *_ = _setup(G=G, KV=KV, Pa=4, seed=G)
     cl = jnp.array([7, 4, 11], jnp.int32)  # ragged, mid-page
     for layer in range(kp.shape[0]):
         _both(q, kn, vn, kp, vp, bt, cl, layer)
+
+
+# contexts per block-table width at ps 4: empty, exactly one block of
+# pages, one token past a block edge, a full table, one short of it, and
+# a lane inside its first page.  Pa 32 runs two 16-page blocks; Pa 20
+# (the 320-page cap's shape, scaled) runs five 4-page blocks.
+BLOCK_CTX = {32: [0, 64, 65, 128, 127, 3], 20: [0, 16, 17, 80, 79, 3]}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8],
+                         ids=["f32", "bf16", "int8"])
+@pytest.mark.parametrize("Pa", [32, 20])
+def test_kernel_page_blocks_ragged(Pa, dtype):
+    q, kn, vn, kp, vp, bt, k_sc, v_sc = _setup(
+        P=Pa + 8, Pa=Pa, B=6, dtype=dtype, seed=Pa
+    )
+    cl = jnp.asarray(BLOCK_CTX[Pa], jnp.int32)
+    _both(q, kn, vn, kp, vp, bt, cl, 1, k_sc, v_sc)
 
 
 def test_kernel_page_boundary_straddles():
@@ -80,48 +110,83 @@ def test_kernel_int8_pages():
     _both(q, kn, vn, kp, vp, bt, cl, 0, k_sc, v_sc)
 
 
-def test_kernel_ignores_unattended_page_contents():
-    """Pages past ctx_len (incl. scratch-page fill in the block table) must
-    not leak into the output, whatever they contain."""
-    q, kn, vn, kp, vp, bt, *_ = _setup(seed=7)
-    # all lanes share one block table row so the attended/poisoned page
-    # sets are disjoint across the batch
-    bt = jnp.broadcast_to(bt[:1], bt.shape)
-    cl = jnp.array([3, 4, 2], jnp.int32)  # only the first page matters
-    out1 = paged_gqa_decode(
-        q, kn, vn, kp, vp, bt, cl, layer=0, interpret=True
-    )
-    # poison every page the block tables point at beyond page 0 of each lane
-    poisoned = kp.at[:, np.asarray(bt[0, 1:])].set(1e4)
-    out2 = paged_gqa_decode(
-        q, kn, vn, poisoned, vp, bt, cl, layer=0, interpret=True
-    )
-    np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), rtol=1e-6)
+def _poisoned(kp, vp, k_sc, v_sc, bt, cl, ps):
+    """The pool with NaN (int8: NaN scales) at every (page, position) no
+    lane attends: pages past each lane's context, and the tail of each
+    lane's last page past ``ctx_len``."""
+    live = np.zeros((kp.shape[1], ps), bool)
+    for row, c in zip(np.asarray(bt), np.asarray(cl)):
+        for i in range(-(-int(c) // ps)):
+            live[row[i], : min(ps, int(c) - i * ps)] = True
+    dead = jnp.asarray(~live)[None, :, None, :]  # (1, P, 1, ps)
+    if k_sc is not None:
+        return (kp, vp, jnp.where(dead, jnp.nan, k_sc),
+                jnp.where(dead, jnp.nan, v_sc))
+    return (jnp.where(dead[..., None], jnp.nan, kp).astype(kp.dtype),
+            jnp.where(dead[..., None], jnp.nan, vp).astype(vp.dtype),
+            None, None)
 
 
-def test_short_lanes_never_dereference_dead_pages():
-    """The index-map clamp: block tables are bucketed to the LONGEST live
-    context, so short lanes carry dead trailing entries.  With the clamp
-    those entries past entry 0 are never dereferenced (the grid step
-    re-asks for the lane's last valid page and Mosaic skips the DMA) — so
-    even garbage page ids past a lane's end must leave the output
-    untouched.  An EMPTY lane clamps every step to entry 0, which must
-    stay a valid page id (the engine's zero-fill/scratch convention)."""
-    q, kn, vn, kp, vp, bt, *_ = _setup(P=9, ps=4, Pa=3, seed=13)
-    cl = jnp.array([5, 0, 12], jnp.int32)  # lane 0 short, lane 1 EMPTY
-    out1 = paged_gqa_decode(q, kn, vn, kp, vp, bt, cl, layer=1,
-                            interpret=True)
-    # rewrite every dead entry PAST entry 0 to an arbitrary other page:
-    # lane 0 attends 2 pages (keeps bt[0,:2]), lane 1 attends none (its
-    # entry 0 stays — the one slot an empty lane still reads), lane 2 all
-    bt2 = np.asarray(bt).copy()
-    bt2[0, 2:] = bt2[2, 0]
-    bt2[1, 1:] = bt2[0, 0]
-    out2 = paged_gqa_decode(q, kn, vn, kp, vp, jnp.asarray(bt2), cl,
-                            layer=1, interpret=True)
+POISON = [pytest.param(dt, KV, id=f"{name}-KV{KV}")
+          for dt, name in ((jnp.bfloat16, "bf16"), (jnp.int8, "int8"))
+          for KV in (1, 2, 8)]
+
+
+@pytest.mark.parametrize("dtype,KV", POISON)
+def test_kernel_ignores_unattended_page_contents(dtype, KV):
+    """Pages past ctx_len (incl. scratch-page fill in the block table),
+    and the positions past ctx_len inside a lane's last page, must not
+    leak into the output, whatever they contain — NaN included."""
+    ps, Pa, B = 4, 8, 4
+    q, kn, vn, kp, vp, _, k_sc, v_sc = _setup(
+        P=B * Pa + 1, Pa=Pa, B=B, KV=KV, dtype=dtype, seed=7
+    )
+    # disjoint pages per lane, so a page one lane leaves dead is no other
+    # lane's live page; lane 3 is empty and its entry 0 points at a
+    # poisoned page, not the scratch page
+    bt = jnp.asarray(1 + np.arange(B * Pa).reshape(B, Pa)[:, ::-1].copy(),
+                     jnp.int32)
+    cl = jnp.array([3, 17, 32, 0], jnp.int32)  # one block = 32 tokens
+    out1 = paged_gqa_decode(q, kn, vn, kp, vp, bt, cl, layer=0,
+                            k_scale=k_sc, v_scale=v_sc, interpret=True)
+    kp2, vp2, ks2, vs2 = _poisoned(kp, vp, k_sc, v_sc, bt, cl, ps)
+    out2 = paged_gqa_decode(q, kn, vn, kp2, vp2, bt, cl, layer=0,
+                            k_scale=ks2, v_scale=vs2, interpret=True)
+    assert np.isfinite(np.asarray(out2)).all()
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
-    # and the clamped kernel still matches the oracle on the ragged batch
-    _both(q, kn, vn, kp, vp, bt, cl, 1)
+    _both(q, kn, vn, kp, vp, bt, cl, 0, k_sc, v_sc)
+
+
+@pytest.mark.parametrize("dtype,KV", POISON)
+def test_short_lanes_never_dereference_dead_pages(dtype, KV):
+    """Block tables are bucketed to the LONGEST live context, so short
+    lanes carry dead trailing entries.  A page block at or past a lane's
+    context is neither fetched nor computed, and inside a lane's last
+    block the pages past its context are skipped — so dead entries,
+    entry 0 of an EMPTY lane included, may point at any page, even one
+    full of NaN, and the output stays bit-identical."""
+    q, kn, vn, kp, vp, bt, k_sc, v_sc = _setup(
+        P=9, ps=4, Pa=4, KV=KV, dtype=dtype, seed=13
+    )
+    cl = jnp.array([5, 0, 16], jnp.int32)  # lane 0 short, lane 1 EMPTY
+    out1 = paged_gqa_decode(q, kn, vn, kp, vp, bt, cl, layer=1,
+                            k_scale=k_sc, v_scale=v_sc, interpret=True)
+    # point every dead entry at page 0, which no lane attends, and fill
+    # page 0 with NaN: lane 0 keeps bt[0, :2], lane 1 keeps nothing (its
+    # entry 0 too), lane 2 attends all four
+    bt2 = np.asarray(bt).copy()
+    bt2[0, 2:] = 0
+    bt2[1, :] = 0
+    if k_sc is None:
+        kp, vp = kp.at[:, 0].set(jnp.nan), vp.at[:, 0].set(jnp.nan)
+    else:
+        k_sc, v_sc = k_sc.at[:, 0].set(jnp.nan), v_sc.at[:, 0].set(jnp.nan)
+    out2 = paged_gqa_decode(q, kn, vn, kp, vp, jnp.asarray(bt2), cl,
+                            layer=1, k_scale=k_sc, v_scale=v_sc,
+                            interpret=True)
+    np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
+    # and the kernel still matches the oracle on the ragged batch
+    _both(q, kn, vn, kp, vp, bt, cl, 1, k_sc, v_sc)
 
 
 def test_epilogue_self_attention_dominates_empty_context():

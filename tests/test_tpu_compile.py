@@ -67,10 +67,10 @@ def test_quant_matmul_compiles(one_chip, n, m, B):
     assert "tpu_custom_call" in text
 
 
-def _pool_shapes(int8: bool):
+def _pool_shapes(int8: bool, kv: int = KV):
     dt = jnp.int8 if int8 else jnp.bfloat16
-    pages = ((CFG.n_layers, PAGES, KV, PAGE, HD), dt)
-    scales = [((CFG.n_layers, PAGES, KV, PAGE), jnp.float32)] * 2
+    pages = ((CFG.n_layers, PAGES, kv, PAGE, HD), dt)
+    scales = [((CFG.n_layers, PAGES, kv, PAGE), jnp.float32)] * 2
     return [pages, pages] + (scales if int8 else [])
 
 
@@ -88,17 +88,32 @@ def _pool_args(args, int8: bool):
 TABLES = [((8, PA), jnp.int32), ((8,), jnp.int32)]
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_paged_decode_compiles(one_chip, int8):
+# (int8, lanes, block-table width, KV heads): the chat cell dispatches 16
+# lanes against 128- to 320-page tables; a two-way TP shard holds 2 KV
+# heads
+DECODE = [
+    pytest.param(False, 8, PA, KV, id="bf16"),
+    pytest.param(True, 8, PA, KV, id="int8"),
+    pytest.param(False, 16, 128, KV, id="bf16-B16-Pa128"),
+    pytest.param(False, 16, 320, KV, id="bf16-B16-Pa320"),
+    pytest.param(False, 16, 256, 2, id="bf16-KV2"),
+    pytest.param(True, 16, 256, 2, id="int8-KV2"),
+]
+
+
+@pytest.mark.parametrize("int8,B,Pa,kv", DECODE)
+def test_paged_decode_compiles(one_chip, int8, B, Pa, kv):
     def step(q, kn, vn, *args):
         kp, vp, bt, cl, kw = _pool_args(args, int8)
         return paged_gqa_decode(q, kn, vn, kp, vp, bt, cl, layer=3,
                                 force_kernel=True, **kw)
 
+    h = kv * (H // KV)
     text = _compiled_text(
         step, one_chip,
-        ((8, H, HD), jnp.bfloat16), ((8, KV, HD), jnp.bfloat16),
-        ((8, KV, HD), jnp.bfloat16), *_pool_shapes(int8), *TABLES,
+        ((B, h, HD), jnp.bfloat16), ((B, kv, HD), jnp.bfloat16),
+        ((B, kv, HD), jnp.bfloat16), *_pool_shapes(int8, kv),
+        ((B, Pa), jnp.int32), ((B,), jnp.int32),
     )
     assert "tpu_custom_call" in text
 
