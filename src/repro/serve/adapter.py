@@ -20,16 +20,16 @@ Two decode paths share the block structure:
     Pallas ``quant_matmul`` kernel path instead of the XLA unpack
     fallback, (2) computes attention *in place* against the physical page
     pool via ``kernels.paged_attention`` (per-lane block tables + context
-    lengths, self-token folded in analytically), and (3) scatters the new
-    K/V into the donated pool tensors.  No per-step dense KV copy exists
-    anywhere in this path.
+    lengths, self-token folded in analytically), and (3) writes the new
+    K/V into the donated pool tensors in place (``kernels.kv_write``).
+    No per-step dense KV copy exists anywhere in this path.
 
 Prefill has the same split: the gather-dense path runs one ``(1, C)``
 chunk per request, while :meth:`prefill_paged` runs a whole padded
 cross-request chunk batch ``(B, C)`` as one jitted dispatch — projections
 through the ``quant_matmul`` kernel dispatch, causal chunk attention over
 the page pool via ``kernels.paged_attention.paged_gqa_prefill`` (ragged
-per-lane prior-context lengths), and a donated in-place scatter of every
+per-lane prior-context lengths), and a donated in-place write of every
 chunk token's K/V (padded tails land on the scratch page).
 
 Two further fused entries ride the same trunks:
@@ -227,8 +227,9 @@ class CachedDecoder:
         """(Re)build the fused paged programs.  Each takes the pool arrays
         ``(k, v, k_scale, v_scale)`` (scales None for fp pages) as one
         donated argument and returns them updated in place by its trailing
-        scatter.  ``rep``/``kv`` optionally pin the output shardings of
-        replicated results and of the pool (distributed adapters)."""
+        :meth:`_write_kv`.  ``rep``/``kv`` optionally pin the output
+        shardings of replicated results and of the pool (distributed
+        adapters)."""
         pin = (lambda *o: {}) if rep is None else (
             lambda *o: {"out_shardings": o}
         )
@@ -252,6 +253,10 @@ class CachedDecoder:
         self._fwd_verify = jax.jit(
             self._forward_verify, donate_argnums=(13,),
             static_argnums=(14,), **pin(rep, rep, rep, kv),
+        )
+        # the pool's host-side writes (the gather-dense path)
+        self._fwd_write = jax.jit(
+            self._write_kv, donate_argnums=(0,), **pin(*(kv or ()))
         )
 
     @property
@@ -292,7 +297,9 @@ class CachedDecoder:
     def make_pool(self, **kw) -> PagedKVPool:
         """Build the engine's KV pool.  Distributed adapters override this
         to place the physical pages sharded over their mesh."""
-        return PagedKVPool(self.cfg, **kw)
+        pool = PagedKVPool(self.cfg, **kw)
+        pool.writer = self._fwd_write
+        return pool
 
     def _place(self, x, dtype=None):
         """Device placement for small per-step host arrays (tokens, block
@@ -529,7 +536,7 @@ class CachedDecoder:
         logits, kn, vn = self._paged_trunk(
             w, tokens, positions, block_tables, ctx_len, kv
         )
-        return logits, write_kv(*kv, pages, offs, kn, vn)
+        return logits, self._write_kv(kv, pages, offs, kn, vn)
 
     # ---- fused decode + on-device selection -------------------------------
 
@@ -584,7 +591,7 @@ class CachedDecoder:
             w, tokens, positions, block_tables, ctx_len, kv
         )
         sel = sample_tokens(logits, temps, top_ps, seeds, draws, greedy_only)
-        return sel, logits, write_kv(*kv, pages, offs, kn, vn)
+        return sel, logits, self._write_kv(kv, pages, offs, kn, vn)
 
     def _block_paged(self, blk, x, positions, layer, kv, block_tables,
                      ctx_len):
@@ -610,6 +617,13 @@ class CachedDecoder:
             layer=layer, k_scale=k_scale, v_scale=v_scale,
             interpret=self.paged_interpret,
         )
+
+    def _write_kv(self, kv, pages, offs, k_new, v_new):
+        """Write a dispatch's new K/V (L, *idx, KV, hd) into the donated
+        pool arrays ``kv`` in place, at the addresses ``pages``/``offs``
+        (idx).  Distributed adapters override this with a ``shard_map``
+        over the model axis, mirroring :meth:`_paged_attention`."""
+        return write_kv(*kv, pages, offs, k_new, v_new, self.paged_interpret)
 
     # ---- paged batched prefill -------------------------------------------
 
@@ -675,7 +689,7 @@ class CachedDecoder:
             w, tokens, positions, block_tables, ctx_len, kv
         )
         # kn/vn (L, B, C, KV, hd); pages/offs (B, C) address every token
-        return logits, write_kv(*kv, pages, offs, kn, vn)
+        return logits, self._write_kv(kv, pages, offs, kn, vn)
 
     # ---- speculative draft-and-verify -------------------------------------
 
@@ -743,7 +757,7 @@ class CachedDecoder:
         )
         sel = sample_tokens(logits, temps, top_ps, seeds, draws, greedy_only)
         n_acc = self._accept(sel, drafts, n_drafts)
-        return sel, n_acc, logits, write_kv(*kv, pages, offs, kn, vn)
+        return sel, n_acc, logits, self._write_kv(kv, pages, offs, kn, vn)
 
     def _block_prefill_paged(self, blk, x, positions, layer, kv,
                              block_tables, ctx_len, verify=False):

@@ -23,8 +23,9 @@ Layers the single-device continuous-batching engine over a
   * **the paged-attention dispatches** — decode, batched chunked
     prefill, AND the speculative verify tick all run under ``shard_map``
     over the model axis: each device attends its local KV-head slice of
-    the pool with its local query-head group, and the donated in-place
-    K/V scatters in the same jitted steps write only local pages.  The
+    the pool with its local query-head group, and the in-place K/V
+    write of the same jitted steps (``_write_kv``, also under
+    ``shard_map``) writes only local pages.  The
     verify/sampling dispatches are re-jitted with pinned
     ``out_shardings`` in :meth:`DistributedCachedDecoder.make_pool`,
     exactly like decode/prefill, so speculative TP serving moves zero
@@ -58,7 +59,7 @@ from repro.kernels.paged_attention.ops import (
 )
 from repro.runtime.sharding import MeshContext, serving_rules
 from repro.serve.adapter import CachedDecoder
-from repro.serve.kv_cache import PagedKVPool
+from repro.serve.kv_cache import PagedKVPool, write_kv
 
 __all__ = [
     "DistributedCachedDecoder",
@@ -291,6 +292,7 @@ class DistributedCachedDecoder(CachedDecoder):
         )
         self._jit_paged_programs(self._rep, shardings)
         self._pool_sharded = spec[2] is not None
+        pool.writer = self._fwd_write
         return pool
 
     def _place(self, x, dtype=None):
@@ -389,6 +391,31 @@ class DistributedCachedDecoder(CachedDecoder):
         )
         return f(q, k_new, v_new, pool_k, pool_v, k_scale, v_scale,
                  block_tables, ctx_len)
+
+    def _write_kv(self, kv, pages, offs, k_new, v_new):
+        """The pool write under ``shard_map``: each model-axis shard
+        writes its own KV-head slice of the new K/V into its slice of the
+        page pool (and of the int8 scales), so the write moves no KV
+        bytes across devices.  Falls back to the replicated path when the
+        pool could not shard."""
+        if not self._pool_sharded:
+            return super()._write_kv(kv, pages, offs, k_new, v_new)
+        lead = (None,) * (1 + pages.ndim)  # (L, *idx, KV, hd)
+        new_spec = P(*lead, "model", None)
+        kv_spec = P(None, None, "model", None, None)
+        sc_spec = P(None, None, "model", None)
+        specs = (kv_spec, kv_spec, sc_spec, sc_spec)  # None scales: empty
+        interpret = self.paged_interpret
+
+        def local(kv, pg, of, kn, vn):
+            return write_kv(*kv, pg, of, kn, vn, interpret)
+
+        f = jax.shard_map(
+            local, mesh=self.mesh,
+            in_specs=(specs, P(), P(), new_spec, new_spec),
+            out_specs=specs, check_vma=False,
+        )
+        return f(kv, pages, offs, k_new, v_new)
 
     def _paged_prefill_attention(self, q, k_new, v_new, pool_k, pool_v,
                                  k_scale, v_scale, block_tables, ctx_len,

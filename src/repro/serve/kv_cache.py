@@ -52,10 +52,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels.kv_write import write_pages
 from repro.serve.faults import NO_FAULTS
 
-__all__ = ["PagedKVPool", "pages_needed", "quantize_kv_int8", "write_pages",
-           "write_kv"]
+__all__ = ["PagedKVPool", "pages_needed", "quantize_kv_int8", "write_kv"]
 
 
 def pages_needed(n_tokens: int, page_size: int) -> int:
@@ -75,35 +75,27 @@ def page_bucket(n_pages: int, cap: int) -> int:
     return min(b, cap)
 
 
-def write_pages(phys: jax.Array, pages: jax.Array, offs: jax.Array,
-                vals: jax.Array) -> jax.Array:
-    """Store ``vals`` (L, *idx, KV[, hd]) at the physical (page, offset)
-    addresses ``pages``/``offs`` (each of shape idx) of a head-major pool
-    (L, P, KV, ps[, hd]) — page tensors and their scales alike."""
-    return phys.at[:, pages, :, offs].set(
-        jnp.moveaxis(vals, 0, pages.ndim).astype(phys.dtype)
-    )
-
-
-def write_kv(k, v, k_scale, v_scale, pages, offs, k_new, v_new):
-    """Scatter new K/V (L, *idx, KV, hd) into pool arrays; int8 pools
-    (``k_scale`` not None) quantize per-(token, head) on the way in.
-    Returns the updated ``(k, v, k_scale, v_scale)``."""
+def write_kv(k, v, k_scale, v_scale, pages, offs, k_new, v_new,
+             interpret=False):
+    """Write new K/V (L, *idx, KV, hd) into pool arrays in place; int8
+    pools (``k_scale`` not None) quantize per-(token, head) on the way in.
+    ``interpret`` runs the Pallas write kernels off-TPU (tests).  Returns
+    the updated ``(k, v, k_scale, v_scale)``."""
     if k_scale is None:
-        return (write_pages(k, pages, offs, k_new),
-                write_pages(v, pages, offs, v_new), None, None)
+        k, v = write_pages((k, v), pages, offs, (k_new, v_new),
+                           interpret=interpret)
+        return k, v, None, None
     kq, ks = quantize_kv_int8(k_new)
     vq, vs = quantize_kv_int8(v_new)
-    return (write_pages(k, pages, offs, kq), write_pages(v, pages, offs, vq),
-            write_pages(k_scale, pages, offs, ks),
-            write_pages(v_scale, pages, offs, vs))
+    return write_pages((k, v, k_scale, v_scale), pages, offs,
+                       (kq, vq, ks, vs), interpret=interpret)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter(phys: jax.Array, pages: jax.Array, offs: jax.Array,
-             vals: jax.Array) -> jax.Array:
-    """phys (L, P, KV, ps, hd); pages/offs (T,); vals (L, T, KV, hd)."""
-    return write_pages(phys, pages, offs, vals)
+def _write(kv: tuple, pages: jax.Array, offs: jax.Array, k_new: jax.Array,
+           v_new: jax.Array) -> tuple:
+    """Host-side write of (L, T, KV, hd) K/V at (T,) addresses."""
+    return write_kv(*kv, pages, offs, k_new, v_new)
 
 
 def quantize_kv_int8(vals: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -116,15 +108,6 @@ def quantize_kv_int8(vals: jax.Array) -> tuple[jax.Array, jax.Array]:
     from repro.models.layers import _quantize_kv
 
     return _quantize_kv(vals)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _scatter_q(phys: jax.Array, scales: jax.Array, pages: jax.Array,
-               offs: jax.Array, vals: jax.Array):
-    """int8 variant: quantize vals (L, T, KV, hd), store values + scales."""
-    q, sc = quantize_kv_int8(vals)
-    return (write_pages(phys, pages, offs, q),
-            write_pages(scales, pages, offs, sc))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -225,6 +208,11 @@ class PagedKVPool:
         # fault-injection hooks (serve/faults.py); the engine points this
         # at its plan — the inert default iterates an empty rule list
         self.faults = NO_FAULTS
+        # device write of new K/V at host-resolved addresses, the pool
+        # donated; an adapter's make_pool swaps in its own jitted
+        # ``_write_kv`` (under shard_map for a pool sharded over KV heads:
+        # a Pallas call cannot be partitioned by GSPMD)
+        self.writer = _write
 
     # ---- accounting -----------------------------------------------------
 
@@ -644,17 +632,8 @@ class PagedKVPool:
 
     def _scatter_kv(self, pages: np.ndarray, offs: np.ndarray,
                     k_new: jax.Array, v_new: jax.Array) -> None:
-        pages, offs = jnp.asarray(pages), jnp.asarray(offs)
-        if self.is_int8:
-            self.k, self.k_scale = _scatter_q(
-                self.k, self.k_scale, pages, offs, k_new
-            )
-            self.v, self.v_scale = _scatter_q(
-                self.v, self.v_scale, pages, offs, v_new
-            )
-        else:
-            self.k = _scatter(self.k, pages, offs, k_new)
-            self.v = _scatter(self.v, pages, offs, v_new)
+        self.arrays = self.writer(self.arrays, jnp.asarray(pages),
+                                  jnp.asarray(offs), k_new, v_new)
 
     def write(
         self,

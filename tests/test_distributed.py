@@ -279,6 +279,54 @@ def test_tp_engine_batched_prefill_int8_token_parity(mesh):
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+def test_tp_engine_write_kernel_token_and_pool_parity(mesh, monkeypatch,
+                                                      kv_int8):
+    """The pool write as the Pallas kernel (interpret mode) under the TP
+    adapter's ``shard_map``, through batched prefill and decode: the
+    single-device engine's tokens, and bit for bit the pool that the XLA
+    scatter writes under the same ``shard_map``."""
+    import repro.serve.distributed as dist_mod
+    from repro.serve.kv_cache import write_kv
+
+    cfg = _smoke_cfg()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    prompts = make_calibration(cfg.vocab, n_segments=2, seg_len=9,
+                               seed=8).tokens
+    kw = dict(paged_prefill=True, kv_int8=kv_int8)
+
+    def tp_run():
+        dist = DistributedCachedDecoder.from_model(
+            model, params, mesh=mesh, paged_interpret=True
+        )
+        eng, toks = _run_engine(dist, prompts, 4, **kw)
+        assert dist._pool_sharded
+        return eng.pool.arrays, toks
+
+    _, t0 = _run_engine(
+        CachedDecoder.from_model(model, params, paged_interpret=True),
+        prompts, 4, **kw,
+    )
+    pool, t1 = tp_run()
+    for a, b in zip(t0, t1):
+        np.testing.assert_array_equal(a, b)
+    # the same engine with the XLA scatter in the shard_map (interpret off
+    # for the write alone)
+    monkeypatch.setattr(dist_mod, "write_kv",
+                        lambda *a: write_kv(*a[:8]))
+    want, t2 = tp_run()
+    for a, b in zip(t1, t2):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(pool, want):
+        if a is None:
+            assert b is None
+            continue
+        np.testing.assert_array_equal(
+            np.asarray(a).view(np.uint8), np.asarray(b).view(np.uint8)
+        )
+
+
 def test_tp_engine_prefix_cache_token_parity(mesh):
     """Prefix-cache hits over the sharded pool: the COW page copy and
     shared-page mapping are layout-preserving (host accounting is device-

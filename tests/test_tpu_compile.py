@@ -139,3 +139,73 @@ def test_paged_prefill_compiles(one_chip, int8, verify):
         *_pool_shapes(int8), *TABLES,
     )
     assert "tpu_custom_call" in text
+
+
+FULL_P = 2200  # the chat cell's pool: a whole-pool copy is 5.37 GiB
+
+
+def _pool_copies(text: str, kv: int) -> list[str]:
+    """Lines of optimized HLO that copy a pool-shaped array (pages or
+    scales) from one layout to another."""
+    shapes = (f"[2,{FULL_P},{kv},{PAGE},{HD}]", f"[2,{FULL_P},{kv},{PAGE}]")
+    return [
+        line for line in text.splitlines()
+        if " copy(" in line and any(s + "{" in line for s in shapes)
+    ]
+
+
+# (token addresses, int8, KV heads, decode kernels reading the pool
+# first): decode writes one token per lane, prefill a (lanes, chunk)
+# batch; a two-way TP shard holds 2 KV heads
+WRITES = [
+    pytest.param(idx, int8, kv, False, id=f"{name}-{dt}-KV{kv}")
+    for name, idx in (("decode", (16,)), ("prefill", (4, 512)))
+    for int8, dt in ((False, "bf16"), (True, "int8"))
+    for kv in (KV, 2)
+] + [
+    pytest.param((16,), int8, KV, True, id=f"decode-reads-first-{dt}")
+    for int8, dt in ((False, "bf16"), (True, "int8"))
+]
+
+
+@pytest.mark.parametrize("idx,int8,kv,reads", WRITES)
+def test_pool_write_is_in_place(one_chip, monkeypatch, idx, int8, kv,
+                                reads):
+    """``write_kv`` into a donated full-size pool compiles to the aliased
+    Pallas write and no pool-sized relayout copy (the XLA scatter moved
+    each pool array out of and back into its layout, four copies per
+    bf16 program).  With ``reads``, two paged decode kernels first read
+    the same pool, as a decode program does."""
+    from repro.kernels.kv_write import ops as kv_write_ops
+    from repro.serve.kv_cache import write_kv
+
+    monkeypatch.setattr(kv_write_ops, "on_tpu", lambda: True)
+    B = idx[0]
+
+    def step(k, v, ks, vs, pages, offs, kn, vn, q, bt, cl):
+        if reads:
+            for layer in (0, 1):
+                kw = {"k_scale": ks, "v_scale": vs} if int8 else {}
+                q = paged_gqa_decode(
+                    q, kn[layer], vn[layer], k, v, bt, cl, layer=layer,
+                    force_kernel=True, **kw,
+                ).astype(jnp.bfloat16)
+        return q, write_kv(k, v, ks, vs, pages, offs, kn, vn)
+
+    dt = jnp.int8 if int8 else jnp.bfloat16
+    pages = ((2, FULL_P, kv, PAGE, HD), dt)
+    scales = ((2, FULL_P, kv, PAGE), jnp.float32)
+    shapes = [pages, pages] + ([scales, scales] if int8 else [])
+    h = kv * (H // KV)
+    rest = [
+        ((*idx,), jnp.int32), ((*idx,), jnp.int32),
+        ((2, *idx, kv, HD), jnp.bfloat16), ((2, *idx, kv, HD), jnp.bfloat16),
+        ((B, h, HD), jnp.bfloat16), ((B, 128), jnp.int32), ((B,), jnp.int32),
+    ]
+    sh = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    pool = [sh(*a) for a in shapes] + [None] * (4 - len(shapes))
+    text = jax.jit(step, donate_argnums=(0, 1, 2, 3)).lower(
+        *pool, *[sh(*a) for a in rest]
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _pool_copies(text, kv) == []
